@@ -7,9 +7,9 @@ aggregate ``G = sum_i scale_i * U_i``.  Run separately
 (client_norm.client_sqnorms_pallas + masked_aggregate.masked_scale_aggregate_pallas)
 that is two full passes over HBM.  This kernel emits BOTH outputs from a
 single ``(clients, chunk)`` tile stream: each grid step reads one tile,
-row-reduces the squares into the resident ``(clients,)`` squared-norm
-accumulator AND contracts the ``(clients,) @ (clients, chunk)`` matvec into
-its ``(chunk,)`` slice of the aggregate — one HBM read per update element,
+row-reduces the squares into the resident squared-norm accumulator AND
+contracts the scale row against the tile into its ``chunk`` slice of the
+aggregate — one HBM read per update element,
 total, for the whole post-plan reduction work of a round.
 
 The single-pass scan engine (fl/engine.py) is the consumer: post-plan, each
@@ -19,11 +19,12 @@ tiles — the norms re-emitted on the aggregate pass are a zero-cost cache
 integrity signal (they must equal pass 1's, which
 tests/test_norm_aggregate.py gates).
 
-Grid: (num_chunks,).  Blocks: the ``(C,)`` scale vector and the ``(C,)``
-squared-norm accumulator map to the same block every step (both stay resident
-in VMEM); updates stream as ``(C, CHUNK)`` tiles; the aggregate output block
-``(CHUNK,)`` at chunk ``i`` is touched by exactly one grid step, so only the
-norm output needs cross-step accumulation.
+Grid: (num_chunks,).  Blocks: the ``(1, C)`` scale row and the ``(C, 1)``
+squared-norm column map to the same block every step (both stay resident in
+VMEM); updates stream as ``(C, CHUNK)`` tiles; the aggregate output block
+``(1, CHUNK)`` at chunk ``i`` is touched by exactly one grid step, so only the
+norm output needs cross-step accumulation.  Every operand is 2-D, the form
+the TPU compiler accepts for the contraction.
 
 In-stream compression (``compress_norm_scale_aggregate_pallas``): the same
 tile stream additionally applies the unbiased compressor — the pure
@@ -42,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.compression import MATERIAL_ARITY, apply_compression_flat
+from repro.kernels.masked_aggregate import scale_contract
 
 
 def _norm_agg_kernel(s_ref, x_ref, sq_ref, o_ref):
@@ -52,40 +54,37 @@ def _norm_agg_kernel(s_ref, x_ref, sq_ref, o_ref):
         sq_ref[...] = jnp.zeros_like(sq_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    sq_ref[...] += jnp.sum(x * x, axis=-1)
-    o_ref[...] = jax.lax.dot_general(
-        s_ref[...].astype(jnp.float32), x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    sq_ref[...] += jnp.sum(x * x, axis=-1, keepdims=True)
+    o_ref[...] = scale_contract(s_ref[...], x)
 
 
 def norm_scale_aggregate_pallas(
     updates: jax.Array, scale: jax.Array, chunk: int = 4096, interpret: bool = False
 ):
-    """updates: (clients, D), scale: (clients,) ->
-    ((clients,) f32 squared norms, (D,) f32 aggregate), one HBM pass.
+    """updates: (clients, D), scale: (1, clients) ->
+    ((clients, 1) f32 squared norms, (1, D) f32 aggregate), one HBM pass.
 
     D is padded to a multiple of ``chunk`` by the wrapper in ops.py (zero
-    padding changes neither output).
+    padding changes neither output), which also reshapes the vectors.
     """
     c, d = updates.shape
-    assert scale.shape == (c,), (scale.shape, c)
+    assert scale.shape == (1, c), (scale.shape, c)
     assert d % chunk == 0, (d, chunk)
     grid = (d // chunk,)
     return pl.pallas_call(
         _norm_agg_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((c,), lambda i: (0,)),
+            pl.BlockSpec((1, c), lambda i: (0, 0)),
             pl.BlockSpec((c, chunk), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
+            pl.BlockSpec((c, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, chunk), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((c,), jnp.float32),
-            jax.ShapeDtypeStruct((d,), jnp.float32),
+            jax.ShapeDtypeStruct((c, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
         interpret=interpret,
     )(scale, updates)
@@ -113,11 +112,8 @@ def _make_compress_norm_agg_kernel(kind: str, param: float, n_mats: int,
         x = x_ref[...].astype(jnp.float32)
         xc = apply_compression_flat(x, kind, param, *[m[...] for m in mat_refs])
         xc = xc.astype(in_dtype).astype(jnp.float32)
-        sq_ref[...] += jnp.sum(xc * xc, axis=-1)
-        o_ref[...] = jax.lax.dot_general(
-            s_ref[...].astype(jnp.float32), xc, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        sq_ref[...] += jnp.sum(xc * xc, axis=-1, keepdims=True)
+        o_ref[...] = scale_contract(s_ref[...], xc)
 
     return kernel
 
@@ -126,8 +122,11 @@ def compress_norm_scale_aggregate_pallas(
     updates: jax.Array, scale: jax.Array, mats: tuple, kind: str, param: float,
     chunk: int = 4096, interpret: bool = False,
 ):
-    """updates (clients, D) + material -> ((clients,) sq norms of C(U),
-    (D,) aggregate of C(U)) — compression fused into the same tile stream.
+    """updates (clients, D) + material -> ((clients, 1) sq norms of C(U),
+    (1, D) aggregate of C(U)) — compression fused into the same tile stream.
+
+    ``scale`` is the ``(1, clients)`` row, as in
+    :func:`norm_scale_aggregate_pallas`.
 
     ``mats`` is the tuple of ``(clients, D)`` f32 material matrices
     (``core.compression.compression_material`` flattened client-major, one
@@ -140,7 +139,7 @@ def compress_norm_scale_aggregate_pallas(
     to zero for every kind, so padding changes neither output).
     """
     c, d = updates.shape
-    assert scale.shape == (c,), (scale.shape, c)
+    assert scale.shape == (1, c), (scale.shape, c)
     assert d % chunk == 0, (d, chunk)
     assert len(mats) == MATERIAL_ARITY[kind], (kind, len(mats))
     for m in mats:
@@ -152,16 +151,16 @@ def compress_norm_scale_aggregate_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((c,), lambda i: (0,)),
+            pl.BlockSpec((1, c), lambda i: (0, 0)),
             pl.BlockSpec((c, chunk), lambda i: (0, i)),
         ] + [pl.BlockSpec((c, chunk), lambda i: (0, i)) for _ in mats],
         out_specs=[
-            pl.BlockSpec((c,), lambda i: (0,)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
+            pl.BlockSpec((c, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, chunk), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((c,), jnp.float32),
-            jax.ShapeDtypeStruct((d,), jnp.float32),
+            jax.ShapeDtypeStruct((c, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
         interpret=interpret,
     )(scale, updates, *mats)

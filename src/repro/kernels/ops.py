@@ -1,11 +1,14 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Every wrapper follows one convention set: the trailing model dim is padded to
-a multiple of ``chunk`` (padding rows/columns are zeros, so reductions and
-contractions are unaffected), ``interpret`` defaults to backend detection (on
-this CPU container the kernels execute in ``interpret=True`` mode — the
-kernel body runs in Python, validating the exact TPU program — while on a
-real TPU the compiled kernel runs), and outputs are unpadded before return.
+Every wrapper follows one convention set: ``chunk`` is capped by
+:func:`fit_chunk` so the streamed tiles fit the TPU's scoped VMEM, the
+trailing model dim is padded to a multiple of it (padding rows/columns are
+zeros, so reductions and contractions are unaffected), vectors travel to the
+kernels as 2-D rows or columns (the TPU compiler refuses a contraction over
+a 1-D operand), ``interpret`` defaults to backend detection (on a CPU
+backend the kernels execute in ``interpret=True`` mode — the kernel body
+runs in Python, validating the exact TPU program — while on a TPU the
+compiled kernel runs), and outputs are reshaped and unpadded before return.
 
 Paper contract (see docs/paper_map.md for the full table):
 
@@ -25,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.core.compression import MATERIAL_ARITY
 from repro.kernels.client_norm import client_sqnorms_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.masked_aggregate import masked_scale_aggregate_pallas
@@ -43,20 +47,36 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def get_shard_map():
-    """(shard_map callable, replication-check-off kwargs) for this jax.
+# bytes of double-buffered streamed tiles one kernel may hold in VMEM: a
+# quarter of the TPU's 16 MiB scoped limit, leaving the rest for the
+# resident scale/norm blocks and the in-kernel temporaries of the compressor
+TILE_BYTES = 4 << 20
 
-    jax >= 0.6 exposes ``shard_map`` at top level (the replication check is
-    named ``check_vma``); earlier versions ship it under ``jax.experimental``
-    with the check named ``check_rep``.  Shared by every module that builds a
-    shard_map (fl/shard_round.py, the mesh-level wrapper below) so the compat
-    logic exists once.
+
+def fit_chunk(chunk: int, d: int, rows: int, streams: int = 1) -> int:
+    """The tile width a kernel streams: ``chunk`` capped by VMEM and by ``d``.
+
+    ``rows`` is the tile height (the client count, or ``block_clients`` on
+    the per-shard grid) and ``streams`` the number of ``(rows, chunk)``
+    operands streamed per grid step (1 + the compressor's material arity).
+    Each is double-buffered at 4 B per element (f32; rows padded to the
+    8-row sublane tile), and the width is the largest multiple of 128 lanes
+    whose ``2 x streams x rows x chunk x 4 B`` stays within ``TILE_BYTES``
+    — never below 128 lanes.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map, {"check_vma": False}
-    from jax.experimental.shard_map import shard_map as _shard_map
+    rows = -(-max(rows, 1) // 8) * 8
+    lanes = TILE_BYTES // (2 * streams * rows * 4) // 128 * 128
+    return min(chunk, max(lanes, 128), max(d, 1))
 
-    return _shard_map, {"check_rep": False}
+
+def get_shard_map():
+    """(shard_map callable, replication-check-off kwargs).
+
+    Shared by every module that builds a shard_map (fl/shard_round.py,
+    sim/pool.py, the mesh-level wrapper below), so each turns the
+    replication check off the same way.
+    """
+    return jax.shard_map, {"check_vma": False}
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -65,7 +85,7 @@ def client_sqnorms(updates: jax.Array, chunk: int = 4096, interpret: bool | None
     if interpret is None:
         interpret = not _on_tpu()
     c, d = updates.shape
-    chunk = min(chunk, max(d, 1))
+    chunk = fit_chunk(chunk, d, c)
     pad = (-d) % chunk
     if pad:
         updates = jnp.pad(updates, ((0, 0), (0, pad)))
@@ -123,12 +143,13 @@ def masked_scale_aggregate(updates: jax.Array, scale: jax.Array, chunk: int = 40
     if interpret is None:
         interpret = not _on_tpu()
     c, d = updates.shape
-    chunk = min(chunk, max(d, 1))
+    chunk = fit_chunk(chunk, d, c)
     pad = (-d) % chunk
     if pad:
         updates = jnp.pad(updates, ((0, 0), (0, pad)))
-    out = masked_scale_aggregate_pallas(updates, scale, chunk=chunk, interpret=interpret)
-    return out[:d]
+    out = masked_scale_aggregate_pallas(updates, scale.reshape(1, c),
+                                        chunk=chunk, interpret=interpret)
+    return out[0, :d]
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -147,13 +168,13 @@ def norm_scale_aggregate(updates: jax.Array, scale: jax.Array, chunk: int = 4096
     if interpret is None:
         interpret = not _on_tpu()
     c, d = updates.shape
-    chunk = min(chunk, max(d, 1))
+    chunk = fit_chunk(chunk, d, c)
     pad = (-d) % chunk
     if pad:
         updates = jnp.pad(updates, ((0, 0), (0, pad)))
-    sq, agg = norm_scale_aggregate_pallas(updates, scale, chunk=chunk,
-                                          interpret=interpret)
-    return sq, agg[:d]
+    sq, agg = norm_scale_aggregate_pallas(updates, scale.reshape(1, c),
+                                          chunk=chunk, interpret=interpret)
+    return sq[:, 0], agg[0, :d]
 
 
 @partial(jax.jit, static_argnames=("kind", "param", "chunk", "interpret"))
@@ -175,16 +196,16 @@ def compress_norm_scale_aggregate(updates, scale, mats, kind: str, param: float,
     if interpret is None:
         interpret = not _on_tpu()
     c, d = updates.shape
-    chunk = min(chunk, max(d, 1))
+    chunk = fit_chunk(chunk, d, c, 1 + MATERIAL_ARITY[kind])
     pad = (-d) % chunk
     if pad:
         updates = jnp.pad(updates, ((0, 0), (0, pad)))
         mats = tuple(jnp.pad(m, ((0, 0), (0, pad))) for m in mats)
     sq, agg = compress_norm_scale_aggregate_pallas(
-        updates, scale, tuple(mats), kind, param, chunk=chunk,
+        updates, scale.reshape(1, c), tuple(mats), kind, param, chunk=chunk,
         interpret=interpret,
     )
-    return sq, agg[:d]
+    return sq[:, 0], agg[0, :d]
 
 
 def tree_masked_aggregate(updates_tree, scale, chunk: int = 4096, interpret=None):
@@ -227,17 +248,17 @@ def shard_masked_aggregate(updates, scale, axis_name: str | None = None,
     if interpret is None:
         interpret = not _on_tpu()
     c, d = updates.shape
-    chunk = min(chunk, max(d, 1))
     block_clients = min(block_clients, max(c, 1))
+    chunk = fit_chunk(chunk, d, block_clients)
     pad_d = (-d) % chunk
     pad_c = (-c) % block_clients
     if pad_d or pad_c:
         updates = jnp.pad(updates, ((0, pad_c), (0, pad_d)))
         scale = jnp.pad(scale, (0, pad_c))
     out = sharded_masked_aggregate_pallas(
-        updates, scale, chunk=chunk, block_clients=block_clients,
-        interpret=interpret,
-    )[:d]
+        updates, scale.reshape(1, -1), chunk=chunk,
+        block_clients=block_clients, interpret=interpret,
+    )[0, :d]
     if axis_name is not None:
         out = jax.lax.psum(out, axis_name)
     return out
@@ -283,8 +304,8 @@ def shard_compress_aggregate(updates, scale, mats, kind: str, param: float,
     if interpret is None:
         interpret = not _on_tpu()
     c, d = updates.shape
-    chunk = min(chunk, max(d, 1))
     block_clients = min(block_clients, max(c, 1))
+    chunk = fit_chunk(chunk, d, block_clients, 1 + MATERIAL_ARITY[kind])
     pad_d = (-d) % chunk
     pad_c = (-c) % block_clients
     if pad_d or pad_c:
@@ -292,10 +313,10 @@ def shard_compress_aggregate(updates, scale, mats, kind: str, param: float,
         scale = jnp.pad(scale, (0, pad_c))
         mats = tuple(jnp.pad(m, ((0, pad_c), (0, pad_d))) for m in mats)
     sq, out = sharded_compress_aggregate_pallas(
-        updates, scale, tuple(mats), kind, param, chunk=chunk,
+        updates, scale.reshape(1, -1), tuple(mats), kind, param, chunk=chunk,
         block_clients=block_clients, interpret=interpret,
     )
-    sq, out = sq[:c], out[:d]
+    sq, out = sq[:c, 0], out[0, :d]
     if axis_name is not None:
         out = jax.lax.psum(out, axis_name)
     return sq, out

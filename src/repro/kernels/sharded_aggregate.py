@@ -31,14 +31,15 @@ large (``n / axis_size`` clients), so the grid gains a client-block axis:
   Grid: ``(num_chunks, num_client_blocks)`` — chunk-major so each output
   chunk is revisited across the *inner* client-block steps and the f32
   accumulator stays resident in VMEM.
-  Blocks: updates ``(BC, CHUNK)`` tile; scale ``(BC,)`` slice; output
-  ``(CHUNK,)`` at chunk ``i``, initialised at client-block 0 and accumulated
-  in-place afterwards.
+  Blocks: updates ``(BC, CHUNK)`` tile; scale ``(1, BC)`` slice of the scale
+  row; output ``(1, CHUNK)`` of the aggregate row at chunk ``i``, initialised
+  at client-block 0 and accumulated in-place afterwards.
 
-Each (scale-slice) x (tile) product is a ``(BC,) @ (BC, CHUNK)`` matvec —
-MXU-friendly — and masking is folded into the contraction (zero scale for
-unsampled clients), so the shard never writes a scaled per-client
-intermediate: one pass over the local block's HBM, one ``(CHUNK,)`` VMEM
+Each (scale-slice) x (tile) product is a ``(1, BC) @ (BC, CHUNK)`` matmul —
+2-D operands, the form the TPU compiler accepts — and masking is folded
+into the contraction (zero scale for unsampled clients), so the shard never
+writes a scaled per-client
+intermediate: one pass over the local block's HBM, one ``(1, CHUNK)`` VMEM
 accumulator per output chunk.
 """
 
@@ -49,6 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.compression import MATERIAL_ARITY, apply_compression_flat
+from repro.kernels.masked_aggregate import scale_contract
 
 
 def _shard_agg_kernel(s_ref, x_ref, o_ref):
@@ -58,11 +60,7 @@ def _shard_agg_kernel(s_ref, x_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    s = s_ref[...].astype(jnp.float32)
-    o_ref[...] += jax.lax.dot_general(
-        s, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    o_ref[...] += scale_contract(s_ref[...], x_ref[...])
 
 
 def sharded_masked_aggregate_pallas(
@@ -72,7 +70,8 @@ def sharded_masked_aggregate_pallas(
     block_clients: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """Local ``(k, D)`` client block -> ``(D,)`` f32 partial aggregate.
+    """Local ``(k, D)`` client block, ``(1, k)`` scale row -> ``(1, D)`` f32
+    partial aggregate.
 
     The shard-local half of Eq. 2: ``partial = sum_i scale_i * U_i`` over the
     clients this shard owns; callers ``psum`` the result over the client mesh
@@ -81,7 +80,7 @@ def sharded_masked_aggregate_pallas(
     zero-scale padding rows contribute nothing to the sum).
     """
     c, d = updates.shape
-    assert scale.shape == (c,), (scale.shape, c)
+    assert scale.shape == (1, c), (scale.shape, c)
     assert d % chunk == 0, (d, chunk)
     assert c % block_clients == 0, (c, block_clients)
     grid = (d // chunk, c // block_clients)
@@ -89,11 +88,11 @@ def sharded_masked_aggregate_pallas(
         _shard_agg_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_clients,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_clients), lambda i, j: (0, j)),
             pl.BlockSpec((block_clients, chunk), lambda i, j: (j, i)),
         ],
-        out_specs=pl.BlockSpec((chunk,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
+        out_specs=pl.BlockSpec((1, chunk), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
     )(scale, updates)
 
@@ -128,11 +127,8 @@ def _make_shard_compress_kernel(kind: str, param: float, n_mats: int,
         x = x_ref[...].astype(jnp.float32)
         xc = apply_compression_flat(x, kind, param, *[m[...] for m in mat_refs])
         xc = xc.astype(in_dtype).astype(jnp.float32)
-        sq_ref[...] += jnp.sum(xc * xc, axis=-1)
-        o_ref[...] += jax.lax.dot_general(
-            s_ref[...].astype(jnp.float32), xc, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        sq_ref[...] += jnp.sum(xc * xc, axis=-1, keepdims=True)
+        o_ref[...] += scale_contract(s_ref[...], xc)
 
     return kernel
 
@@ -147,8 +143,9 @@ def sharded_compress_aggregate_pallas(
     block_clients: int = 128,
     interpret: bool = False,
 ):
-    """Local ``(k, D)`` RAW client block + material -> ``((k,) f32 squared
-    norms of C(U), (D,) f32 partial aggregate of C(U))``, compression fused.
+    """Local ``(k, D)`` RAW client block + material, ``(1, k)`` scale row ->
+    ``((k, 1) f32 squared norms of C(U), (1, D) f32 partial aggregate of
+    C(U))``, compression fused.
 
     The shard-local half of Eq. 2 with the compressor run inside the same
     tile stream: ``partial = sum_i scale_i * C(U_i)`` over the clients this
@@ -160,7 +157,7 @@ def sharded_compress_aggregate_pallas(
     material rows/columns contribute nothing to either output).
     """
     c, d = updates.shape
-    assert scale.shape == (c,), (scale.shape, c)
+    assert scale.shape == (1, c), (scale.shape, c)
     assert d % chunk == 0, (d, chunk)
     assert c % block_clients == 0, (c, block_clients)
     assert len(mats) == MATERIAL_ARITY[kind], (kind, len(mats))
@@ -172,17 +169,17 @@ def sharded_compress_aggregate_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_clients,), lambda i, j: (j,)),
+            pl.BlockSpec((1, block_clients), lambda i, j: (0, j)),
             pl.BlockSpec((block_clients, chunk), lambda i, j: (j, i)),
         ] + [pl.BlockSpec((block_clients, chunk), lambda i, j: (j, i))
              for _ in mats],
         out_specs=[
-            pl.BlockSpec((block_clients,), lambda i, j: (j,)),
-            pl.BlockSpec((chunk,), lambda i, j: (i,)),
+            pl.BlockSpec((block_clients, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, chunk), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((c,), jnp.float32),
-            jax.ShapeDtypeStruct((d,), jnp.float32),
+            jax.ShapeDtypeStruct((c, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
         interpret=interpret,
     )(scale, updates, *mats)

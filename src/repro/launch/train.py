@@ -86,6 +86,7 @@ from repro.configs import get
 from repro.configs.base import FLConfig
 from repro.fl.engine import make_engine
 from repro.fl.round import client_weights, round_bits
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 
 
@@ -259,6 +260,8 @@ def run_scenario_cli(args):
 
 
 def main(argv=None):
+    """Parse ``argv`` and run one branch.  The arch branch returns its
+    per-round ``{"loss": [...], "wall_s": [...]}``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="assigned architecture to train (omit with --scenario)")
@@ -349,6 +352,7 @@ def main(argv=None):
                          "needs no recompute (0 = two-pass recompute; "
                          ">= clients/scan-group = single-pass)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.scenario:
         return run_scenario_cli(args)
@@ -358,7 +362,10 @@ def main(argv=None):
         args.rounds = 10
 
     cfg = get(args.arch)
-    model = build_model(cfg, remat=False)
+    # remat: each client's local step keeps one layer's activations, not all
+    # of them — without it a mamba2-130m scan group (2 clients x 4 x 512
+    # tokens) needs more than a v5e's 16 GB of HBM
+    model = build_model(cfg)
     system, over = parse_stragglers(args.stragglers, args.deadline)
     server_opt = None
     if args.server_opt == "momentum":
@@ -425,6 +432,7 @@ def main(argv=None):
         from repro.obs import Telemetry
 
         tel = Telemetry(obs)
+    history = {"loss": [], "wall_s": []}
     diag_on = tel is not None and tel.cfg.diag_every > 0
     if diag_on and mesh is not None:
         raise SystemExit(
@@ -556,6 +564,8 @@ def main(argv=None):
         loss = float(m.loss)
         total_bits += round_bits(fl, dim, m.mask)
         wall_s = time.perf_counter() - t0
+        history["loss"].append(loss)
+        history["wall_s"].append(wall_s)
         if diag:
             tel.record_gap(k, float(m.gap.gap_sq), float(m.gap.full_sq))
         if tel is not None:
@@ -573,6 +583,7 @@ def main(argv=None):
     if tel is not None:
         tel.finish(rounds=args.rounds)
         tel.close()
+    return history
 
 
 if __name__ == "__main__":

@@ -333,13 +333,17 @@ def _round_lines(text):
             for line in text.splitlines() if line.startswith("[round")]
 
 
-def test_train_cli_checkpoints_full_state(tmp_path, capsys):
+def test_train_cli_checkpoints_full_state(tmp_path, capsys, monkeypatch):
     """Regression for the params-only --checkpoint bug: the training CLI's
     checkpoint must carry the server-opt state (and RNG/sampler/client
     state), and a --resume run must print the exact round lines (loss,
     alpha, sent, bits) the uninterrupted run prints — momentum makes a
     dropped opt state visibly diverge."""
     from repro.launch import train
+
+    # train.main turns the persistent compile cache on unless this variable
+    # is set; set after jax read it, it keeps this test process uncached
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
 
     d = str(tmp_path / "ck")
     base = ["--arch", "llama3-8b-reduced", "--rounds", "4", "--clients", "2",
