@@ -5,8 +5,9 @@ Telemetry for the whole round path, strictly additive: phase spans
 estimator (``‖ŝ − s‖²`` between the sampled and the full-participation
 aggregate, observed per round), a schema-versioned JSONL event stream, and
 a stdlib-threaded live metrics endpoint (JSON snapshot + Prometheus text
-exposition).  With telemetry off nothing here runs and every pre-existing
-path is bit-for-bit unchanged (gated by tests/test_obs.py).
+exposition).  With telemetry off only the spans' profiler annotations run
+(no sync, no record) and every result is bit-for-bit unchanged (gated by
+tests/test_obs.py).
 
 Entry points: build an :class:`ObsConfig` and hand it to
 ``repro.sim.driver.run_simulation(obs=...)`` (or ``launch/train.py

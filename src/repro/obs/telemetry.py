@@ -40,8 +40,9 @@ class ObsConfig:
     bitwise identical; XLA fusion domains differ, so params agree only to
     float tolerance — keep it off for bit-exactness checks).
 
-    The default-constructed config is inert: ``enabled`` is False and the
-    driver takes the exact pre-obs code path.
+    The default-constructed config is inert: ``enabled`` is False, and the
+    driver's results are bitwise those of ``obs=None`` (its spans only
+    annotate the profiler trace).
     """
 
     diag_every: int = 0
@@ -191,8 +192,8 @@ class Telemetry:
 def as_telemetry(obs) -> "tuple[Optional[Telemetry], bool]":
     """Normalize a driver ``obs=`` argument to ``(telemetry, owned)``.
 
-    ``None`` / inert :class:`ObsConfig` → ``(None, False)`` (telemetry off,
-    pre-obs code path); an enabled :class:`ObsConfig` → a fresh Telemetry
+    ``None`` / inert :class:`ObsConfig` → ``(None, False)`` (telemetry off:
+    annotation-only spans, no sync); an enabled :class:`ObsConfig` → a fresh Telemetry
     the driver must close (``owned=True``); a :class:`Telemetry` instance →
     passed through with ``owned=False`` (caller keeps lifecycle — the CI
     obs-smoke scrapes the endpoint after the run, then closes it).

@@ -1,14 +1,19 @@
 """Phase spans: monotonic wall-time measurement + profiler trace annotation.
 
 :func:`span` is the one timing primitive of the obs layer — a context
-manager that (a) opens a ``jax.profiler.TraceAnnotation`` so the phase shows
-up as a named slice in TensorBoard/Perfetto dumps, and (b) records the
-phase's wall time on the monotonic clock (``time.perf_counter`` — never
-``time.time``, which NTP can step backwards mid-run).  Because JAX dispatch
-is asynchronous, a naive exit timestamp would measure *enqueue* time only;
-the span object therefore takes a ``block(x)`` target whose arrays are
+manager that (a) opens a ``jax.profiler.TraceAnnotation`` named
+``repro.obs/<name>`` so the phase shows up as a named slice in
+TensorBoard/Perfetto dumps and on the host plane of a ``.xplane.pb``, on the
+same clock as the device's ops, and (b) records the phase's wall time on
+the monotonic clock (``time.perf_counter`` — never ``time.time``, which NTP
+can step backwards mid-run).  Because JAX dispatch is asynchronous, a naive
+exit timestamp would measure *enqueue* time only; a span that records into
+a sink therefore takes a ``block(x)`` target whose arrays are
 ``jax.block_until_ready``-waited before the clock stops, so the recorded
-seconds bound the device work of the phase, not just its dispatch.
+seconds bound the device work of the phase, not just its dispatch.  A span
+with no sink records nowhere and never waits: it only annotates, so the
+sim driver keeps its spans open on the hot path whether telemetry is on or
+off, and the results stay bitwise the same.
 
 :class:`TraceWindow` is the ``--trace-dir`` support: it wraps the first N
 rounds of a run in ``jax.profiler.start_trace`` / ``stop_trace`` so a
@@ -42,7 +47,8 @@ class Span:
 
     def block(self, arrays) -> None:
         """Arrays to ``jax.block_until_ready`` before the span closes, so the
-        recorded wall time covers the phase's device work."""
+        recorded wall time covers the phase's device work.  Only a span with
+        a sink waits; one that records nowhere ignores the target."""
         self._block = arrays
 
 
@@ -50,13 +56,15 @@ class Span:
 def span(name: str, sink=None):
     """Time one phase on the monotonic clock, annotated for the profiler.
 
-    Yields a :class:`Span`; call ``sp.block(arrays)`` with the phase's output
-    so the device work is ``block_until_ready``-bounded before the clock
-    stops.  ``sink`` (a :class:`~repro.obs.telemetry.Telemetry`, or anything
-    with ``record_span(name, seconds)``) receives the measurement; with
-    ``sink=None`` the span still annotates the profiler trace but records
-    nowhere.  The wall time is ``time.perf_counter`` based — monotonic, so
-    committed baselines cannot be corrupted by NTP steps.
+    Yields a :class:`Span`.  ``sink`` (a
+    :class:`~repro.obs.telemetry.Telemetry`, or anything with
+    ``record_span(name, seconds)``) receives the measurement, and then
+    ``sp.block(arrays)`` makes the phase's device work
+    ``block_until_ready``-bounded before the clock stops.  With
+    ``sink=None`` the span only opens the ``repro.obs/<name>`` annotation
+    and reads the clock (``sp.seconds`` is the dispatch time): it never
+    syncs the device.  The wall time is ``time.perf_counter`` based —
+    monotonic, so committed baselines cannot be corrupted by NTP steps.
     """
     sp = Span(name)
     t0 = time.perf_counter()
@@ -64,7 +72,7 @@ def span(name: str, sink=None):
         try:
             yield sp
         finally:
-            if sp._block is not None:
+            if sink is not None and sp._block is not None:
                 jax.block_until_ready(sp._block)
             sp.seconds = time.perf_counter() - t0
             if sink is not None:

@@ -68,7 +68,10 @@ the prefetch loop gains a per-round device sync so wall times are honest
 (the observer effect; docs/observability.md).  The gap estimator is
 single-device only (rejected with a mesh); ``ObsConfig.phases`` applies to
 host-mode vmap engines and is ignored elsewhere (scan rounds are timed at
-block granularity).
+block granularity).  With or without telemetry, the call names its host
+work (set-up, pool, cohort plans, dispatch, compile, ledger) with
+``repro.obs/`` profiler annotations that never sync
+(docs/observability.md, "Host spans").
 
 A ``checkpoint`` argument (:class:`repro.checkpoint.CheckpointConfig`, or a
 bare directory path) writes a full-fidelity
@@ -88,7 +91,6 @@ tests/test_resume.py and the ``resume-smoke`` CI job
 
 from __future__ import annotations
 
-import contextlib
 import copy
 import dataclasses
 import json
@@ -150,16 +152,6 @@ LEDGER_SERIES = (
 # sparse per-diagnostic-round series (schema 3; empty when the run had no
 # obs gap estimator) — all four the same length, indexed by gap_rounds
 GAP_SERIES = ("gap_rounds", "gap_sq", "gap_full_sq", "gap_ratio")
-
-
-class _NullSpan:
-    """No-op stand-in for :class:`repro.obs.trace.Span` when telemetry is off."""
-
-    def block(self, arrays) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
 
 
 @dataclass
@@ -403,53 +395,52 @@ def run_simulation(
             "scan-over-rounds (docs/architecture.md#limits)"
         )
 
-    # mesh-aware engine selection, BEFORE any RNG or device work: with a
-    # mesh, host/prefetch run the explicit-collective shard_map round; a
-    # rejected config (unknown compressor/backend, server_opt on the mesh)
-    # raises here — no key is consumed and no pool is uploaded.
-    engine = None
-    if mesh is not None:
-        round_step_fn = make_engine(loss_fn, fl, server_opt, mesh=mesh)
-        step_factory = lambda diag=False: round_step_fn
-    else:
-        engine = RoundEngine(loss_fn, fl, server_opt)
-        step_factory = engine.make_step
-    # phased execution (real per-phase spans) applies to host-mode vmap
-    # engines only; elsewhere the knob is ignored and rounds are timed as
-    # whole "round" spans (scan: one span per block).
-    use_phased = (
-        tel is not None and tel.cfg.phases and mode == "host"
-        and engine is not None and engine.memory == "vmap"
-    )
-
-    def sp(name):
-        # span when telemetry is on; inert no-op context otherwise, so the
-        # obs=None path stays exactly the pre-obs code.
-        if tel is not None:
-            return obs_span(name, tel)
-        return contextlib.nullcontext(_NULL_SPAN)
-
-    rng = np.random.default_rng(seed)
-    key = jax.random.PRNGKey(seed)
-    params = init_fn(jax.random.fold_in(key, 1))
-    dim = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
-    opt_state = server_opt.init(params) if server_opt is not None else ()
-    # client-state layer: chains over the WHOLE dataset pool, initialised at
-    # stationarity from a dedicated fold (the params fold is 1, rounds are
-    # 1000+k — fold 2 is untouched on every pre-existing path).
-    state = None
-    if system is not None:
-        state = init_client_state(
-            dataset.n_clients, system, jax.random.fold_in(key, 2)
+    # every span is annotated for the profiler; those given `tel` also
+    # record into it and wait on their block target, so with telemetry off
+    # no span syncs (obs/trace.py).  The call's set-up, before any pool or
+    # round: engine, params, client-state and sampler init.
+    with obs_span("setup"):
+        # mesh-aware engine selection, BEFORE any RNG or device work: with a
+        # mesh, host/prefetch run the explicit-collective shard_map round; a
+        # rejected config (unknown compressor/backend, server_opt on the mesh)
+        # raises here — no key is consumed and no pool is uploaded.
+        engine = None
+        if mesh is not None:
+            round_step_fn = make_engine(loss_fn, fl, server_opt, mesh=mesh)
+            step_factory = lambda diag=False: round_step_fn
+        else:
+            engine = RoundEngine(loss_fn, fl, server_opt)
+            step_factory = engine.make_step
+        # phased execution (real per-phase spans) applies to host-mode vmap
+        # engines only; elsewhere the knob is ignored and rounds are timed as
+        # whole "round" spans (scan: one span per block).
+        use_phased = (
+            tel is not None and tel.cfg.phases and mode == "host"
+            and engine is not None and engine.memory == "vmap"
         )
-        state_step = jax.jit(
-            lambda st, kk, c: step_client_state(st, kk, c, system)
-        )
-    # stateful samplers (cyclic/threshold): their SamplerState rides through
-    # the round loop exactly like the client-state chain — fed into every
-    # round_step, read back from metrics.sampler_state (host/prefetch) or
-    # carried in the lax.scan carry (scan mode).
-    samp = init_sampler_state() if is_stateful(fl.sampler) else None
+
+        rng = np.random.default_rng(seed)
+        key = jax.random.PRNGKey(seed)
+        params = init_fn(jax.random.fold_in(key, 1))
+        dim = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
+        opt_state = server_opt.init(params) if server_opt is not None else ()
+        # client-state layer: chains over the WHOLE dataset pool, initialised at
+        # stationarity from a dedicated fold (the params fold is 1, rounds are
+        # 1000+k — fold 2 is untouched on every pre-existing path).
+        state = None
+        if system is not None:
+            state = init_client_state(
+                dataset.n_clients, system, jax.random.fold_in(key, 2)
+            )
+            state_step = jax.jit(
+                lambda st, kk, c: step_client_state(st, kk, c, system)
+            )
+        # stateful samplers (cyclic/threshold): their SamplerState rides through
+        # the round loop exactly like the client-state chain — fed into every
+        # round_step, read back from metrics.sampler_state (host/prefetch) or
+        # carried in the lax.scan carry (scan mode).
+        samp = init_sampler_state() if is_stateful(fl.sampler) else None
+
     sizes = np.asarray(dataset.sizes())
     uniform_w = client_weights(fl)
 
@@ -465,6 +456,18 @@ def run_simulation(
 
     def want_eval(k):
         return eval_fn is not None and (k % eval_every == 0 or k == rounds - 1)
+
+    compiled = False
+
+    def dispatch(step, *args):
+        # the call's first dispatch of its step traces, lowers and compiles
+        # it (or loads it from the persistent cache): a nested "compile" span
+        nonlocal compiled
+        if compiled:
+            return step(*args)
+        compiled = True
+        with obs_span("compile"):
+            return step(*args)
 
     dev_metrics = []          # device-side RoundMetrics (stacked blocks in scan)
     dev_evals = []            # (round, device scalar)
@@ -627,7 +630,7 @@ def run_simulation(
             diag = diag_on and tel.want_gap(k)
             if tel is not None:
                 tel.round_start(k)
-            with sp("data") as s:
+            with obs_span("data", tel) as s:
                 clients = draw_cohort()
                 w = cohort_weights(clients)
                 batch = dataset.sample_round_batches(
@@ -646,9 +649,9 @@ def run_simulation(
                 )
             else:
                 step = round_step_diag if diag else round_step
-                with sp("round") as s:
-                    params, opt_state, metrics = step(
-                        params, opt_state, batch, w, kk, trace, samp
+                with obs_span("round", tel) as s:
+                    params, opt_state, metrics = dispatch(
+                        step, params, opt_state, batch, w, kk, trace, samp
                     )
                     s.block(metrics.loss)
             if samp is not None:
@@ -683,16 +686,18 @@ def run_simulation(
             # advances round by round even though round k+1's draw (and its
             # state step) is dispatched while round k still computes.
             nonlocal state
-            clients = draw_cohort()
-            plan = cpool.plan(rng, clients, fl.local_steps, batch_size, local_epoch)
+            with obs_span("plan"):
+                clients = draw_cohort()
+                plan = cpool.plan(rng, clients, fl.local_steps, batch_size, local_epoch)
             kk = jax.random.fold_in(key, 1000 + k)
             trace = None
             if state is not None:
                 state, trace = state_step(state, kk, jnp.asarray(plan.clients))
             return plan, cohort_weights(clients), kk, trace
 
-        cur = draw_round(k0)
-        cur_batch = cpool.gather(cur[0])
+        with obs_span("data", tel):
+            cur = draw_round(k0)
+            cur_batch = cpool.gather(cur[0])
         for k in range(k0, rounds):
             t_round = time.perf_counter()
             diag = diag_on and tel.want_gap(k)
@@ -710,13 +715,14 @@ def run_simulation(
             if k + 1 < rounds:
                 # double buffering: round k+1's plan is drawn and its gather
                 # dispatched while round k's step is still executing.
-                with sp("data") as s:
+                with obs_span("data", tel):
                     cur = draw_round(k + 1)
                     cur_batch = cpool.gather(cur[0])
-            with sp("round") as s:
-                params, opt_state, metrics = (
-                    round_step_diag if diag else round_step
-                )(params, opt_state, batch, w, kk, trace, samp)
+            with obs_span("round", tel) as s:
+                params, opt_state, metrics = dispatch(
+                    round_step_diag if diag else round_step,
+                    params, opt_state, batch, w, kk, trace, samp,
+                )
                 s.block(metrics.loss)
             if samp is not None:
                 samp = metrics.sampler_state
@@ -730,8 +736,10 @@ def run_simulation(
                 jax.block_until_ready(metrics.loss)
             if t_first is None:
                 # the only telemetry-off mid-run sync: marks the end of the
-                # compile round
-                jax.block_until_ready(metrics.loss)
+                # compile round, and waits for all queued before it — the
+                # rest of the pool's asynchronous copy to the device too
+                with obs_span("first_sync"):
+                    jax.block_until_ready(metrics.loss)
                 t_first, first_units = time.perf_counter(), 1
             # telemetry off, this is dispatch cadence, not device time
             wall_ms.append((time.perf_counter() - t_round) * 1e3)
@@ -807,21 +815,25 @@ def run_simulation(
                 while not want_eval(nxt):
                     nxt += 1
                 span = min(span, nxt - done + 1)
-            with sp("data") as s:
-                plans, w_s, keys_s = [], [], []
-                for k in range(done, done + span):
-                    clients = draw_cohort()
-                    plans.append(
-                        cpool.plan(rng, clients, fl.local_steps, batch_size, local_epoch)
-                    )
-                    w_s.append(cohort_weights(clients))
-                    keys_s.append(jax.random.fold_in(key, 1000 + k))
-                clients_s, take_s, smask_s = stack_plans(plans)
-            with sp("round") as s:
-                params, opt_state, state, samp, ms = chunk(
-                    cpool.buffers, params, opt_state, state, samp,
-                    jnp.asarray(clients_s), jnp.asarray(take_s), jnp.asarray(smask_s),
-                    jnp.stack(w_s), jnp.stack(keys_s),
+            with obs_span("data", tel):
+                with obs_span("plan"):
+                    plans, w_s, keys_s = [], [], []
+                    for k in range(done, done + span):
+                        clients = draw_cohort()
+                        plans.append(
+                            cpool.plan(rng, clients, fl.local_steps, batch_size, local_epoch)
+                        )
+                        w_s.append(cohort_weights(clients))
+                        keys_s.append(jax.random.fold_in(key, 1000 + k))
+                    clients_s, take_s, smask_s = stack_plans(plans)
+                with obs_span("gather"):
+                    # the block's index uploads; the gather itself runs in
+                    # the scan body
+                    xs = (jnp.asarray(clients_s), jnp.asarray(take_s),
+                          jnp.asarray(smask_s), jnp.stack(w_s), jnp.stack(keys_s))
+            with obs_span("round", tel) as s:
+                params, opt_state, state, samp, ms = dispatch(
+                    chunk, cpool.buffers, params, opt_state, state, samp, *xs
                 )
                 s.block(ms.loss)
             dev_metrics.append(ms)
@@ -829,7 +841,8 @@ def run_simulation(
             if want_eval(done - 1):
                 dev_evals.append((done - 1, eval_fn(params, eval_batch)))
             if t_first is None:
-                jax.block_until_ready(ms.loss)
+                with obs_span("first_sync"):
+                    jax.block_until_ready(ms.loss)
                 t_first, first_units = time.perf_counter(), span
             # telemetry on, s.block already synced the block, so this is an
             # honest per-round amortisation; telemetry off it is the block's
@@ -857,45 +870,48 @@ def run_simulation(
         jax.block_until_ready(dev_metrics[-1].loss)
     t_end = time.perf_counter()
 
-    ledger = SimLedger(
-        mode=mode,
-        scenario=scenario_name,
-        fl=dataclasses.asdict(fl),
-        workload={
-            "rounds": rounds,
-            "batch_size": batch_size,
-            "pool_clients": int(dataset.n_clients),
-            "model_dim": dim,
-            "seed": seed,
-            "local_epoch": bool(local_epoch),
-            "backend_platform": jax.default_backend(),
-            **({"rounds_per_scan": rounds_per_scan} if mode == "scan" else {}),
-            **({"pool_bytes": cpool.nbytes} if mode != "host" else {}),
-            **(
-                {"mesh_axis_size": int(np.prod(mesh.devices.shape))}
-                if mesh is not None else {}
-            ),
-            **(
-                {"system": dataclasses.asdict(system)}
-                if system is not None else {}
-            ),
-        },
-    )
-    # the resumed tail (if any) splices ahead of this process's live rounds
-    # with identical scalar conversions — byte-identical artifact either way
-    ser, masks_all, norms_all = splice_series()
-    for name in LEDGER_SERIES:
-        setattr(ledger, name, ser[name])
-    ledger.masks = list(masks_all)
-    ledger.norms = list(norms_all)
-    for k, gs, fs in gap_records:
-        ledger.gap_rounds.append(int(k))
-        ledger.gap_sq.append(gs)
-        ledger.gap_full_sq.append(fs)
-        ledger.gap_ratio.append(_obs_gap_ratio(gs, fs))
-    for k, v in dev_evals:
-        ledger.acc_rounds.append(int(k))
-        ledger.acc.append(float(v))
+    # the ledger: the per-round device reads and the host arithmetic over
+    # them, after the final sync (an annotation only; nothing records it)
+    with obs_span("ledger"):
+        ledger = SimLedger(
+            mode=mode,
+            scenario=scenario_name,
+            fl=dataclasses.asdict(fl),
+            workload={
+                "rounds": rounds,
+                "batch_size": batch_size,
+                "pool_clients": int(dataset.n_clients),
+                "model_dim": dim,
+                "seed": seed,
+                "local_epoch": bool(local_epoch),
+                "backend_platform": jax.default_backend(),
+                **({"rounds_per_scan": rounds_per_scan} if mode == "scan" else {}),
+                **({"pool_bytes": cpool.nbytes} if mode != "host" else {}),
+                **(
+                    {"mesh_axis_size": int(np.prod(mesh.devices.shape))}
+                    if mesh is not None else {}
+                ),
+                **(
+                    {"system": dataclasses.asdict(system)}
+                    if system is not None else {}
+                ),
+            },
+        )
+        # the resumed tail (if any) splices ahead of this process's live rounds
+        # with identical scalar conversions — byte-identical artifact either way
+        ser, masks_all, norms_all = splice_series()
+        for name in LEDGER_SERIES:
+            setattr(ledger, name, ser[name])
+        ledger.masks = list(masks_all)
+        ledger.norms = list(norms_all)
+        for k, gs, fs in gap_records:
+            ledger.gap_rounds.append(int(k))
+            ledger.gap_sq.append(gs)
+            ledger.gap_full_sq.append(fs)
+            ledger.gap_ratio.append(_obs_gap_ratio(gs, fs))
+        for k, v in dev_evals:
+            ledger.acc_rounds.append(int(k))
+            ledger.acc.append(float(v))
     ledger.wall_s = t_end - t_start
     # throughput counts the rounds THIS process ran, not the resumed tail
     steady = (rounds - k0) - first_units

@@ -51,6 +51,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.ocs import AvailabilityTrace
+from repro.obs.trace import span
 
 # fold constant deriving the client-state key from the round key.  The round
 # engines consume the round key as ``k_sample, k_comp = split(key)``; folding
@@ -114,6 +115,16 @@ def gather_batch(buffers, clients, take, step_mask):
     return batch
 
 
+def _padded(client_data, key, rows, max_examples):
+    """One data key of every client, zero-padded into a host buffer of
+    ``(rows, max_examples, ...)``."""
+    first = client_data[0][key]
+    buf = np.zeros((rows, max_examples) + first.shape[1:], first.dtype)
+    for i, d in enumerate(client_data):
+        buf[i, : len(d[key])] = d[key]
+    return buf
+
+
 @jax.jit
 def _gather_jit(buffers, clients, take, step_mask):
     return gather_batch(buffers, clients, take, step_mask)
@@ -151,15 +162,17 @@ class ClientPool:
         rows = self.n_clients + (-self.n_clients) % self.axis_size
         self.rows_per_shard = rows // self.axis_size
         sharding = None if mesh is None else NamedSharding(mesh, P(client_axis))
-        buffers = {}
-        for k, first in dataset.client_data[0].items():
-            buf = np.zeros((rows, self.max_examples) + first.shape[1:], first.dtype)
-            for i, d in enumerate(dataset.client_data):
-                buf[i, : len(d[k])] = d[k]
-            buffers[k] = (
-                jnp.asarray(buf) if sharding is None else jax.device_put(buf, sharding)
-            )
-        self.buffers = buffers
+        with span("pool_build"):
+            host = {
+                k: _padded(dataset.client_data, k, rows, self.max_examples)
+                for k in dataset.client_data[0]
+            }
+        with span("pool_upload"):
+            # dispatches the copies only: they run asynchronously, holding
+            # the host buffers, and complete before the first gather runs
+            put = (jnp.asarray if sharding is None
+                   else lambda b: jax.device_put(b, sharding))
+            self.buffers = {k: put(b) for k, b in host.items()}
         self._sharded_gather = None if mesh is None else self._build_sharded_gather()
 
     @property
@@ -215,24 +228,25 @@ class ClientPool:
         Sharded mode returns the batch with every leaf sharded
         ``P(client_axis)`` — ready for the shard_map round's in_specs.
         """
-        if self._sharded_gather is None:
-            return _gather_jit(
+        with span("gather"):
+            if self._sharded_gather is None:
+                return _gather_jit(
+                    self.buffers,
+                    jnp.asarray(plan.clients),
+                    jnp.asarray(plan.take),
+                    jnp.asarray(plan.step_mask),
+                )
+            # host side of the per-shard index plan: owner shard + local row
+            # of every cohort position (cohort ORDER is untouched — parity).
+            owner = plan.clients // self.rows_per_shard
+            local_row = plan.clients % self.rows_per_shard
+            return self._sharded_gather(
                 self.buffers,
-                jnp.asarray(plan.clients),
+                jnp.asarray(owner.astype(np.int32)),
+                jnp.asarray(local_row.astype(np.int32)),
                 jnp.asarray(plan.take),
                 jnp.asarray(plan.step_mask),
             )
-        # host side of the per-shard index plan: owner shard + local row of
-        # every cohort position (cohort ORDER is untouched — parity).
-        owner = plan.clients // self.rows_per_shard
-        local_row = plan.clients % self.rows_per_shard
-        return self._sharded_gather(
-            self.buffers,
-            jnp.asarray(owner.astype(np.int32)),
-            jnp.asarray(local_row.astype(np.int32)),
-            jnp.asarray(plan.take),
-            jnp.asarray(plan.step_mask),
-        )
 
 
 @dataclass(frozen=True)

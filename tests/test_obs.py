@@ -10,7 +10,9 @@ estimator — including the subsystem's two acceptance gates:
   schema-3 ledger is byte-identical minus those fields.
 """
 
+import glob
 import json
+import os
 import time
 import urllib.error
 import urllib.request
@@ -41,7 +43,7 @@ from repro.obs import (
 from repro.obs.events import read_events
 from repro.obs.phased import make_phased_step
 from repro.obs.trace import PHASES
-from repro.sim import run_scenario, validate_ledger
+from repro.sim import run_scenario, run_simulation, validate_ledger
 
 
 @pytest.fixture(scope="module")
@@ -86,6 +88,85 @@ def test_span_times_and_records():
     with span("sample") as sp2:
         pass
     assert sp2.seconds >= 0.0
+
+
+@pytest.mark.parametrize("with_sink", [False, True], ids=["no-sink", "sink"])
+def test_span_blocks_only_with_a_sink(monkeypatch, with_sink):
+    """A span that records nowhere never syncs the device: its block target
+    is waited on only when a sink receives the measurement."""
+    waits = []
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: waits.append(x) or x)
+
+    class Sink:
+        def record_span(self, name, seconds):
+            pass
+
+    with span("round", Sink() if with_sink else None) as sp:
+        sp.block(jnp.zeros(3))
+    assert len(waits) == (1 if with_sink else 0)
+    assert sp.seconds >= 0.0
+
+
+def _host_spans(log_dir):
+    """``[(start_ns, end_ns, name)]`` of the ``repro.obs/`` annotations on the
+    host thread that recorded them, from the profiler's ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    lines = [line for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU" for line in plane.lines]
+    per_line = [[(e.start_ns, e.start_ns + e.duration_ns, e.name[len("repro.obs/"):])
+                 for e in line.events if e.name.startswith("repro.obs/")]
+                for line in lines]
+    (spans,) = [sp for sp in per_line if sp]     # one thread: the caller's
+    return sorted(spans, key=lambda t: (t[0], -t[1]))
+
+
+def _inside(outer, inner):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+@pytest.mark.parametrize("mode,units", [("prefetch", 4), ("scan", 2)])
+def test_telemetry_off_spans_in_the_trace(small_ds, tmp_path, mode, units):
+    """With telemetry off the driver still names its host work in the
+    profiler trace, on the calling thread and properly nested: the call's
+    pool build and upload, set-up, first sync and ledger once; ``data``
+    (holding one ``plan`` and one ``gather``) and ``round`` once per round
+    (prefetch) or per block (scan); one ``compile`` inside the first
+    ``round``."""
+    init, loss, _ = mlp_classifier(small_ds.input_dim, small_ds.num_classes,
+                                   hidden=8)
+    fl = FLConfig(n_clients=4, expected_clients=2, local_steps=2, lr_local=0.1)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        run_simulation(small_ds, init, loss, fl, 4, batch_size=4, mode=mode,
+                       rounds_per_scan=2)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    by_name = {}
+    for sp_ in spans:
+        by_name.setdefault(sp_[2], []).append(sp_)
+    counts = {n: len(v) for n, v in by_name.items()}
+    assert counts == {"setup": 1, "pool_build": 1, "pool_upload": 1, "ledger": 1,
+                      "first_sync": 1, "data": units, "plan": units,
+                      "gather": units, "round": units, "compile": 1}
+    # spans of one thread nest: any two are disjoint or one holds the other
+    for i, a in enumerate(spans):
+        for b in spans[i + 1:]:
+            assert b[0] >= a[1] or _inside(a, b), (a, b)
+    for d in by_name["data"]:
+        inner = [s for s in spans if s is not d and _inside(d, s)]
+        assert sorted(s[2] for s in inner) == ["gather", "plan"]
+    assert _inside(by_name["round"][0], by_name["compile"][0])
+    # set-up, pool, rounds and ledger follow one another in the call; the
+    # first sync ends the first round
+    order = [s[2] for s in spans if s[2] in ("setup", "pool_build", "pool_upload",
+                                             "round", "first_sync", "ledger")]
+    assert order == (["setup", "pool_build", "pool_upload", "round", "first_sync"]
+                     + ["round"] * (units - 1) + ["ledger"])
 
 
 def test_phase_contract_names():
