@@ -768,6 +768,8 @@ def run_simulation(
         if not use_samp:
             samp = ()  # empty SamplerState carry slot for stateless samplers
 
+        shapes = cpool.example_shapes
+
         def chunk_fn(buffers, params, opt_state, st, sp, clients_s, take_s,
                      smask_s, w_s, keys_s):
             def body(carry, xs):
@@ -780,7 +782,7 @@ def run_simulation(
                     # host/prefetch jitted state step — bitwise identical.
                     s, trace = step_client_state(s, kk, c, system)
                 p, o, m = step_fn(
-                    p, o, gather_batch(buffers, c, t, sm), w, kk, trace,
+                    p, o, gather_batch(buffers, shapes, c, t, sm), w, kk, trace,
                     sp if use_samp else None,
                 )
                 if use_samp:

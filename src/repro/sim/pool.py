@@ -4,10 +4,28 @@ The legacy host loop rebuilt every round's cohort batch with numpy fancy
 indexing and re-uploaded it — O(cohort · batch bytes) of host work and
 host→device traffic per round, fully serialized with the jitted round step.
 The :class:`ClientPool` inverts that: the whole ``FederatedDataset`` is
-padded/stacked ONCE into device-resident ``(pool, max_examples, ...)``
-buffers, and a round cohort becomes two tiny index arrays (client ids +
-per-client example rows) that a jitted gather turns into the
-``(n, R, b, ...)`` round batch entirely on device.
+padded/stacked ONCE into device-resident buffers, and a round cohort becomes
+two tiny index arrays (client ids + per-client example rows) that a jitted
+gather turns into the ``(n, R, b, ...)`` round batch entirely on device.
+
+**Layout.** The gather reads whole examples, so every buffer keeps each
+example contiguous and row-major: ``(rows, max_examples, F)`` with ``F`` the
+product of the example shape (``(rows, max_examples)`` where ``F`` is 1).
+A TPU picks a buffer's default layout by the least tile padding: left at
+``F = 784`` it makes the client axis minor, and every gather then first
+copied the whole pool into row-major order (a pool-sized temp in every
+call).  So the device buffer pads its minor axis to whole 128-lane tiles and
+``max_examples`` to whole sublane tiles (:func:`device_shape`), where
+row-major needs no padding and is the default; padding is zeros and the
+gather slices it off.  The layout stays the default one on purpose: a
+layout requested through ``jax.experimental.layout`` does not survive JAX's
+persistent compilation cache (an executable read back from it does not keep
+the requested layout: a TPU refuses the buffer, a CPU misreads it), and the
+program runs with that cache on.  Flattening also
+keeps a small minor dimension (an image's 3 channels) from padding on its
+own.  The upload copies each host buffer in flat row blocks, which the
+runtime moves without tiling them on the host, and tiles them on the device
+(:func:`_to_device`).
 
 The driver (repro/sim/driver.py) runs that gather as a **double-buffered
 host→device prefetch pipeline**: while round k's jitted step is still
@@ -41,6 +59,7 @@ the sampling masks) is untouched — sharding only changes WHERE rows live.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import NamedTuple
@@ -52,6 +71,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.ocs import AvailabilityTrace
 from repro.obs.trace import span
+
+# a TPU vector register's lanes: the minor tile of every layout
+LANES = 128
+# bytes of one host-to-device copy of the pool upload (``_to_device``)
+UPLOAD_BYTES = 256 << 20
 
 # fold constant deriving the client-state key from the round key.  The round
 # engines consume the round key as ``k_sample, k_comp = split(key)``; folding
@@ -96,48 +120,152 @@ def plan_cohort(rng, sizes, clients, max_steps, batch_size, local_epoch=True):
     return RoundPlan(clients.astype(np.int32), take, step_mask)
 
 
-def gather_batch(buffers, clients, take, step_mask):
+def gather_batch(buffers, shapes, clients, take, step_mask):
     """Pure (traceable) cohort gather: pool buffers -> ``(n, R, b, ...)`` batch.
 
-    Used both by the jitted :meth:`ClientPool.gather` and *inside* the
-    driver's scan-over-rounds body, where ``clients``/``take``/``step_mask``
-    are one round's slice of the stacked block plans.
+    ``shapes`` holds ``(key, example shape)`` pairs (``ClientPool.
+    example_shapes``): each gathered ``(n, R, b, F_pad)`` leaf drops its
+    lane padding and is reshaped back to ``(n, R, b) + shape``.  Used both
+    by the jitted :meth:`ClientPool.gather` and *inside* the driver's
+    scan-over-rounds body, where ``clients``/``take``/``step_mask`` are one
+    round's slice of the stacked block plans.
     """
 
-    def one(buf):
+    def one(buf, shape):
         # one fused gather: (n, R, b) example rows straight out of the
-        # (pool, max_examples, ...) buffer — no (n, max_examples, ...)
+        # (pool, max_examples, F) buffer — no (n, max_examples, F)
         # per-cohort intermediate is ever materialised.
-        return buf[clients[:, None, None], take]
+        rows = buf[clients[:, None, None], take]
+        if buf.ndim == 3:
+            rows = rows[..., : math.prod(shape)]
+        return rows.reshape(take.shape + shape)
 
-    batch = {k: one(v) for k, v in buffers.items()}
+    batch = {k: one(buffers[k], shape) for k, shape in shapes}
     batch["_step_mask"] = step_mask
     return batch
 
 
 def _padded(client_data, key, rows, max_examples):
     """One data key of every client, zero-padded into a host buffer of
-    ``(rows, max_examples, ...)``."""
+    ``(rows, max_examples, F)``, ``F`` the product of the example shape
+    (``(rows, max_examples)`` where ``F`` is 1)."""
     first = client_data[0][key]
-    buf = np.zeros((rows, max_examples) + first.shape[1:], first.dtype)
+    f = math.prod(first.shape[1:])
+    tail = (f,) if f > 1 else ()
+    buf = np.zeros((rows, max_examples) + tail, first.dtype)
     for i, d in enumerate(client_data):
-        buf[i, : len(d[key])] = d[key]
+        n = len(d[key])
+        buf[i, :n] = d[key].reshape((n,) + tail)
     return buf
 
 
-@jax.jit
-def _gather_jit(buffers, clients, take, step_mask):
-    return gather_batch(buffers, clients, take, step_mask)
+def device_shape(shape, dtype):
+    """A pool buffer's device shape: the minor axis padded to a multiple of
+    128 lanes and, for ``(rows, max_examples, F)``, ``max_examples`` to a
+    whole sublane tile (8 rows of 32-bit values), so that the TPU's default
+    layout of the buffer is row-major (module docstring, Layout)."""
+    *major, minor = shape
+    if len(major) == 2:
+        sub = 8 * max(1, 4 // np.dtype(dtype).itemsize)
+        major[1] = -(-major[1] // sub) * sub
+    return (*major, -(-minor // LANES) * LANES)
+
+
+@functools.partial(jax.jit, static_argnums=3, donate_argnums=0)
+def _write(buf, flat, r0, tail):
+    """The rows ``flat`` holds, each of shape ``tail``, written into the
+    donated ``buf`` from row ``r0``."""
+    piece = flat.reshape((-1,) + tail)
+    return jax.lax.dynamic_update_slice(buf, piece, (r0,) + (0,) * len(tail))
+
+
+def _to_device(host, dev):
+    """``host`` in a zero buffer of its :func:`device_shape` on ``dev``
+    (``None``: the default device, uncommitted), copied in row blocks of
+    about ``UPLOAD_BYTES``, each one flat.
+
+    A flat copy has the trivial 1-D layout, so the runtime moves it without
+    tiling it on the host; :func:`_write` tiles it on the device.  Each
+    block's write completes before the next copy is issued, so at most one
+    block is on the device beside the buffer."""
+    rows = host.shape[0]
+    block = min(rows, max(1, UPLOAD_BYTES // host[0].nbytes))
+    buf = jnp.zeros(device_shape(host.shape, host.dtype), host.dtype, device=dev)
+    for r0 in range(0, rows, block):
+        r0 = min(r0, rows - block)   # the last block overlaps: one shape
+        flat = jax.device_put(host[r0 : r0 + block].reshape(-1), dev)
+        buf = _write(buf, flat, r0, host.shape[1:])
+        buf.block_until_ready()
+    return buf
+
+
+def _upload(host, sharding):
+    """``host`` on the device by :func:`_to_device`: uncommitted on the
+    default device without ``sharding`` (as ``jnp.asarray`` places it, so
+    that jitted outputs stay uncommitted and a step's second call reuses its
+    first compile), else each device's row block, placed by ``sharding``."""
+    if sharding is None:
+        return _to_device(host, None)
+    shape = device_shape(host.shape, host.dtype)
+    shards = [_to_device(host[idx[0]], dev)
+              for dev, idx in sharding.addressable_devices_indices_map(shape).items()]
+    return jax.make_array_from_single_device_arrays(shape, sharding, shards)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _gather_jit(buffers, shapes, clients, take, step_mask):
+    return gather_batch(buffers, shapes, clients, take, step_mask)
+
+
+def _sharded_gather(mesh, axis, shapes):
+    """The jitted shard-local gather + psum_scatter pipeline (module doc)
+    over ``mesh``'s ``axis``, for buffers of ``shapes``' examples."""
+    from repro.kernels.ops import get_shard_map
+
+    axis_size = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
+
+    def body(buffers, owner, local_row, take, step_mask):
+        n = owner.shape[0]
+        k = n // axis_size
+        idx = jax.lax.axis_index(axis)
+        own = owner == idx
+
+        def one(buf, shape):
+            # ONE gather over the shard's local pool slice; positions a
+            # different shard owns read row 0 and are masked to zero, so
+            # the cross-shard psum_scatter reconstructs each position from
+            # its unique owner while handing this shard only its
+            # (k, R, b, ...) cohort slice.
+            rows = buf[jnp.where(own, local_row, 0)[:, None, None], take]
+            if buf.ndim == 3:
+                rows = rows[..., : math.prod(shape)]
+            rows = jnp.where(own.reshape((n,) + (1,) * (rows.ndim - 1)), rows, 0)
+            rows = jax.lax.psum_scatter(rows, axis, scatter_dimension=0, tiled=True)
+            return rows.reshape(rows.shape[:3] + shape)
+
+        batch = {bk: one(buffers[bk], shape) for bk, shape in shapes}
+        batch["_step_mask"] = jax.lax.dynamic_slice_in_dim(step_mask, idx * k, k)
+        return batch
+
+    smap, check = get_shard_map()
+    fn = smap(body, mesh=mesh, in_specs=(P(axis), P(), P(), P(), P()),
+              out_specs=P(axis), **check)
+    return jax.jit(fn)
 
 
 class ClientPool:
     """Device-resident padded copy of a ``FederatedDataset``.
 
-    Every data key is stacked into one ``(pool, max_examples, ...)`` buffer
-    (clients padded with zeros up to the largest client; real rows are always
-    addressed through a :class:`RoundPlan`, so padding is never read).  Built
-    once per simulation; all subsequent per-round work is index generation on
-    the host and a jitted gather on device.
+    Every data key is stacked into one ``(pool, max_examples, F)`` buffer,
+    ``F`` the product of the key's example shape (``(pool, max_examples)``
+    where ``F`` is 1), stored row-major on the device so that each example
+    is contiguous, with :func:`device_shape`'s tile padding (module
+    docstring, Layout); ``example_shapes`` keeps each key's example shape
+    for :func:`gather_batch`.  Clients are padded with zeros up to the
+    largest client; real rows are always addressed through a
+    :class:`RoundPlan`, so padding is never read.  Built once per
+    simulation; all subsequent per-round work is index generation on the
+    host and a jitted gather on device.
 
     With ``mesh`` given, the pool runs in **sharded mode**: the row count
     pads to a multiple of the ``client_axis`` size, every buffer is placed
@@ -162,65 +290,24 @@ class ClientPool:
         rows = self.n_clients + (-self.n_clients) % self.axis_size
         self.rows_per_shard = rows // self.axis_size
         sharding = None if mesh is None else NamedSharding(mesh, P(client_axis))
+        first = dataset.client_data[0]
+        self.example_shapes = tuple((k, v.shape[1:]) for k, v in first.items())
         with span("pool_build"):
-            host = {
-                k: _padded(dataset.client_data, k, rows, self.max_examples)
-                for k in dataset.client_data[0]
-            }
+            host = {k: _padded(dataset.client_data, k, rows, self.max_examples)
+                    for k in first}
         with span("pool_upload"):
-            # dispatches the copies only: they run asynchronously, holding
-            # the host buffers, and complete before the first gather runs
-            put = (jnp.asarray if sharding is None
-                   else lambda b: jax.device_put(b, sharding))
-            self.buffers = {k: put(b) for k, b in host.items()}
-        self._sharded_gather = None if mesh is None else self._build_sharded_gather()
+            self.buffers = {k: _upload(b, sharding) for k, b in host.items()}
+        self._sharded_gather = (None if mesh is None
+                                else _sharded_gather(mesh, client_axis, self.example_shapes))
 
     @property
     def nbytes(self) -> int:
-        """Device bytes held by the padded pool buffers (global, all shards)."""
+        """Bytes of the padded pool buffers (global, all shards)."""
         return sum(int(b.size * b.dtype.itemsize) for b in self.buffers.values())
 
     def plan(self, rng, clients, max_steps, batch_size, local_epoch=True):
         """:func:`plan_cohort` bound to this pool's client sizes."""
         return plan_cohort(rng, self.sizes, clients, max_steps, batch_size, local_epoch)
-
-    def _build_sharded_gather(self):
-        """The jitted shard-local gather + psum_scatter pipeline (module doc)."""
-        from repro.kernels.ops import get_shard_map
-
-        axis, axis_size = self.client_axis, self.axis_size
-
-        def body(buffers, owner, local_row, take, step_mask):
-            n = owner.shape[0]
-            k = n // axis_size
-            idx = jax.lax.axis_index(axis)
-            own = owner == idx
-
-            def one(buf):
-                # ONE gather over the shard's local pool slice; positions a
-                # different shard owns read row 0 and are masked to zero, so
-                # the cross-shard psum_scatter reconstructs each position
-                # from its unique owner while handing this shard only its
-                # (k, R, b, ...) cohort slice.
-                rows = buf[jnp.where(own, local_row, 0)[:, None, None], take]
-                rows = jnp.where(own.reshape((n,) + (1,) * (rows.ndim - 1)), rows, 0)
-                return jax.lax.psum_scatter(
-                    rows, axis, scatter_dimension=0, tiled=True
-                )
-
-            batch = {bk: one(v) for bk, v in buffers.items()}
-            batch["_step_mask"] = jax.lax.dynamic_slice_in_dim(step_mask, idx * k, k)
-            return batch
-
-        smap, check = get_shard_map()
-        fn = smap(
-            body,
-            mesh=self.mesh,
-            in_specs=(P(self.client_axis), P(), P(), P(), P()),
-            out_specs=P(self.client_axis),
-            **check,
-        )
-        return jax.jit(fn)
 
     def gather(self, plan: RoundPlan):
         """Dispatch the (async, jitted) device gather of one round's batch.
@@ -232,6 +319,7 @@ class ClientPool:
             if self._sharded_gather is None:
                 return _gather_jit(
                     self.buffers,
+                    self.example_shapes,
                     jnp.asarray(plan.clients),
                     jnp.asarray(plan.take),
                     jnp.asarray(plan.step_mask),

@@ -6,6 +6,9 @@ client-state layer's determinism regression (same seed => byte-identical
 straggler-cell ledger JSON in all three driver modes)."""
 
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -13,19 +16,22 @@ import numpy as np
 import pytest
 
 from repro.configs.base import FLConfig
-from repro.data import femnist_like
+from repro.data import FederatedDataset, femnist_like
 from repro.fl.engine import RoundEngine
 from repro.fl.round import client_weights
 from repro.fl.trainer import run_training
 from repro.models.simple import mlp_classifier
+from repro.sim import pool as pool_mod
 from repro.sim import (
     ClientPool,
+    build_client_mesh,
     get_scenario,
     list_scenarios,
     run_scenario,
     run_simulation,
     validate_ledger,
 )
+from repro.sim.pool import device_shape
 
 MODES = ("host", "prefetch", "scan")
 
@@ -71,6 +77,88 @@ def test_pool_gather_matches_host_batches(small_ds):
         assert np.array_equal(host[k], np.asarray(dev[k])), k
     # the two paths consumed the RNG identically (streams still in lockstep)
     assert r_host.integers(1 << 30) == r_pool.integers(1 << 30)
+
+
+def _image_ds(n_clients=10, seed=0):
+    """Clients of (8, 8, 3) images: a multi-dimensional example shape."""
+    rng = np.random.default_rng(seed)
+    data = []
+    for _ in range(n_clients):
+        n = int(rng.integers(3, 12))
+        data.append({"x": rng.normal(size=(n, 8, 8, 3)).astype(np.float32),
+                     "y": rng.integers(0, 5, n).astype(np.int32)})
+    return FederatedDataset(data, num_classes=5, input_dim=192)
+
+
+def check_image_pool(mesh, client_axis="data"):
+    """Every pool buffer is row-major at its device shape, with each example
+    contiguous, and a gather returns exactly numpy's fancy-index of the
+    padded host data, in the example shape."""
+    ds = _image_ds()
+    pool = ClientPool(ds, mesh=mesh, client_axis=client_axis)
+    m = pool.max_examples
+    rows = pool.buffers["x"].shape[0]
+    assert pool.buffers["x"].shape == device_shape((rows, m, 8 * 8 * 3), np.float32)
+    assert pool.buffers["y"].shape == device_shape((rows, m), np.int32)
+    for k, buf in pool.buffers.items():
+        assert buf.format.layout.major_to_minor == tuple(range(buf.ndim)), k
+    plan = pool.plan(np.random.default_rng(3), np.array([7, 0, 9, 3]), 3, 4)
+    got = pool.gather(plan)
+    for k in ("x", "y"):
+        first = ds.client_data[0][k]
+        padded = np.zeros((ds.n_clients, m) + first.shape[1:], first.dtype)
+        for i, d in enumerate(ds.client_data):
+            padded[i, : len(d[k])] = d[k]
+        want = padded[plan.clients[:, None, None], plan.take]
+        assert np.array_equal(np.asarray(got[k]), want), k
+    assert np.array_equal(np.asarray(got["_step_mask"]), plan.step_mask)
+    # the whole pool went up: each client's rows read back, padding zero
+    x = np.asarray(pool.buffers["x"])
+    for i, d in enumerate(ds.client_data):
+        n = len(d["x"])
+        assert np.array_equal(x[i, :n, :192], d["x"].reshape(n, 192)), i
+        assert not x[i, n:].any() and not x[i, :, 192:].any(), i
+
+
+POOL_4_DEVICES = """
+import sys
+sys.path.insert(0, {tests!r})
+import jax
+from repro.sim import pool
+from test_sim import check_image_pool
+pool.UPLOAD_BYTES = {upload_bytes}
+check_image_pool(jax.make_mesh((4,), ("data",)))
+print("POOL-4-OK")
+"""
+
+
+@pytest.mark.parametrize("where", ["single", "single-row-blocks", "sharded",
+                                   "sharded-4-devices"])
+def test_pool_row_major_gather_of_images(where, monkeypatch):
+    """The pool's layout rule on a multi-dimensional example shape, on one
+    device (uploaded whole, and in blocks of 3 of its 10 image rows, the
+    last block overlapping), sharded on the live devices' client mesh, and
+    sharded over four forced host devices in blocks of 2 of each shard's 3
+    rows (a subprocess)."""
+    ds = _image_ds()
+    row_bytes = max(len(d["x"]) for d in ds.client_data) * 8 * 8 * 3 * 4
+    if where == "single":
+        check_image_pool(None)
+    elif where == "single-row-blocks":
+        monkeypatch.setattr(pool_mod, "UPLOAD_BYTES", 3 * row_bytes)
+        check_image_pool(None)
+    elif where == "sharded":
+        fl = FLConfig(n_clients=4, expected_clients=2)
+        check_image_pool(build_client_mesh(fl), fl.client_axis)
+    else:
+        tests = os.path.dirname(os.path.abspath(__file__))
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=os.path.join(os.path.dirname(tests), "src"))
+        code = POOL_4_DEVICES.format(tests=tests, upload_bytes=2 * row_bytes)
+        out = subprocess.run([sys.executable, "-c", code],
+                             env=env, capture_output=True, text=True, timeout=300)
+        assert "POOL-4-OK" in out.stdout, out.stdout + out.stderr
 
 
 @pytest.mark.parametrize(
