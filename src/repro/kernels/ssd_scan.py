@@ -47,7 +47,9 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, da_ref, y_ref, state_out_ref,
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
         >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     )
-    decay = jnp.where(tri, jnp.exp(seg), 0.0)
+    # masked before the exponent, as in repro.models.ssm: above the diagonal
+    # seg >= 0 can overflow, and where() after exp() has a NaN gradient there
+    decay = jnp.exp(jnp.where(tri, seg, -jnp.inf))
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (Q, Q)
     att = cb * decay * dt[None, :]

@@ -66,6 +66,7 @@ Examples (CPU container — reduced configs):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import copy
 import dataclasses
 import os
@@ -88,6 +89,7 @@ from repro.fl.engine import make_engine
 from repro.fl.round import client_weights, round_bits
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
+from repro.obs.trace import span as obs_span
 
 
 def synthetic_token_batch(rng, cfg, n, r, b, s):
@@ -361,114 +363,118 @@ def main(argv=None):
     if args.rounds is None:
         args.rounds = 10
 
-    cfg = get(args.arch)
-    # remat: each client's local step keeps one layer's activations, not all
-    # of them — without it a mamba2-130m scan group (2 clients x 4 x 512
-    # tokens) needs more than a v5e's 16 GB of HBM
-    model = build_model(cfg)
-    system, over = parse_stragglers(args.stragglers, args.deadline)
-    server_opt = None
-    if args.server_opt == "momentum":
-        from repro.optim import sgd
+    # the run's set-up, named in any profiler trace as the sim driver's
+    # is: model, engine, params, client-state and sampler init
+    with obs_span("setup"):
+        cfg = get(args.arch)
+        # remat: each client's local step keeps one layer's activations, not
+        # all of them — without it a mamba2-130m scan group (2 clients x 4 x
+        # 512 tokens) needs more than a v5e's 16 GB of HBM
+        model = build_model(cfg)
+        system, over = parse_stragglers(args.stragglers, args.deadline)
+        server_opt = None
+        if args.server_opt == "momentum":
+            from repro.optim import sgd
 
-        server_opt = sgd(args.lr_server, momentum=0.9)
-    elif args.server_opt == "adam":
-        from repro.optim import adam
+            server_opt = sgd(args.lr_server, momentum=0.9)
+        elif args.server_opt == "adam":
+            from repro.optim import adam
 
-        server_opt = adam(args.lr_server)
-    fl = FLConfig(
-        n_clients=args.clients, expected_clients=args.expected,
-        sampler=args.sampler or "aocs",
-        local_steps=args.local_steps, lr_local=args.lr_local,
-        round_engine=args.engine, agg_backend=args.agg_backend,
-        scan_group=args.scan_group, cache_groups=args.cache_groups,
-        over_select=over if over is not None else 1.0,
-    )
-    key = jax.random.PRNGKey(0)
-    params = model.init(key)
-    dim = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
-    opt_state = server_opt.init(params) if server_opt is not None else ()
-    state = state_step = None
-    if system is not None:
-        # arch path: every round's cohort IS the full client set, so the
-        # trace covers all n clients each round.
-        from repro.sim.pool import init_client_state, step_client_state
-
-        state = init_client_state(fl.n_clients, system, jax.random.fold_in(key, 2))
-        state_step = jax.jit(
-            lambda st, kk, c: step_client_state(st, kk, c, system)
+            server_opt = adam(args.lr_server)
+        fl = FLConfig(
+            n_clients=args.clients, expected_clients=args.expected,
+            sampler=args.sampler or "aocs",
+            local_steps=args.local_steps, lr_local=args.lr_local,
+            round_engine=args.engine, agg_backend=args.agg_backend,
+            scan_group=args.scan_group, cache_groups=args.cache_groups,
+            over_select=over if over is not None else 1.0,
         )
+        key = jax.random.PRNGKey(0)
+        params = model.init(key)
+        dim = sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
+        opt_state = server_opt.init(params) if server_opt is not None else ()
+        state = state_step = None
+        if system is not None:
+            # arch path: every round's cohort IS the full client set, so the
+            # trace covers all n clients each round.
+            from repro.sim.pool import init_client_state, step_client_state
 
-    n_dev = jax.device_count()
-    # the shard_map round has no scan/cache memory policy (see
-    # docs/architecture.md#limits): an explicit scan request conflicts with
-    # --shard on, and wins over --shard auto (never silently dropped).
-    if args.shard == "on" and args.engine == "scan":
-        raise SystemExit(
-            "--shard on and --engine scan conflict: the shard_map round has "
-            "no scan/cache memory policy (docs/architecture.md#limits) — "
-            "drop one of the two flags"
-        )
-    shard = args.shard == "on" or (
-        args.shard == "auto" and n_dev > 1 and fl.n_clients % n_dev == 0
-        and args.engine != "scan"
-    )
-    mesh = None
-    if shard:
-        if fl.n_clients % n_dev:
-            raise SystemExit(
-                f"--shard on needs n_clients ({fl.n_clients}) divisible by the "
-                f"device count ({n_dev})"
+            state = init_client_state(fl.n_clients, system, jax.random.fold_in(key, 2))
+            state_step = jax.jit(
+                lambda st, kk, c: step_client_state(st, kk, c, system)
             )
-        mesh = jax.make_mesh((n_dev,), (fl.client_axis,))
-    print(f"[train] {cfg.name}: {dim/1e6:.1f}M params, n={fl.n_clients} m={fl.expected_clients} "
-          f"sampler={fl.sampler} engine={'shard_map/' + str(n_dev) if shard else fl.round_engine} "
-          f"agg={fl.agg_backend}")
-    # obs layer: the arch loop is synchronous (a host loop), so phase spans
-    # and the gap estimator apply exactly as in the sim driver's host mode.
-    obs = obs_from_args(args, mode="host")
-    tel = None
-    if obs is not None:
-        from repro.obs import Telemetry
 
-        tel = Telemetry(obs)
-    history = {"loss": [], "wall_s": []}
-    diag_on = tel is not None and tel.cfg.diag_every > 0
-    if diag_on and mesh is not None:
-        raise SystemExit(
-            "--diag-every and a mesh conflict: the obs gap estimator is "
-            "single-device only (docs/architecture.md#limits) — drop "
-            "--diag-every or pass --shard off"
+        n_dev = jax.device_count()
+        # the shard_map round has no scan/cache memory policy (see
+        # docs/architecture.md#limits): an explicit scan request conflicts with
+        # --shard on, and wins over --shard auto (never silently dropped).
+        if args.shard == "on" and args.engine == "scan":
+            raise SystemExit(
+                "--shard on and --engine scan conflict: the shard_map round has "
+                "no scan/cache memory policy (docs/architecture.md#limits) — "
+                "drop one of the two flags"
+            )
+        shard = args.shard == "on" or (
+            args.shard == "auto" and n_dev > 1 and fl.n_clients % n_dev == 0
+            and args.engine != "scan"
         )
-    phased_step = step_diag = None
-    if mesh is None:
-        from repro.fl.engine import RoundEngine
+        mesh = None
+        if shard:
+            if fl.n_clients % n_dev:
+                raise SystemExit(
+                    f"--shard on needs n_clients ({fl.n_clients}) divisible by the "
+                    f"device count ({n_dev})"
+                )
+            mesh = jax.make_mesh((n_dev,), (fl.client_axis,))
+        engine = f"shard_map/{n_dev}" if shard else fl.round_engine
+        print(f"[train] {cfg.name}: {dim/1e6:.1f}M params, n={fl.n_clients} "
+              f"m={fl.expected_clients} sampler={fl.sampler} engine={engine} "
+              f"agg={fl.agg_backend}")
+        # obs layer: the arch loop is synchronous (a host loop), so phase spans
+        # and the gap estimator apply exactly as in the sim driver's host mode.
+        obs = obs_from_args(args, mode="host")
+        tel = None
+        if obs is not None:
+            from repro.obs import Telemetry
 
-        eng = RoundEngine(model.loss, fl, server_opt)
-        if tel is not None and tel.cfg.phases and eng.memory == "vmap":
-            from repro.obs.phased import make_phased_step
+            tel = Telemetry(obs)
+        history = {"loss": [], "wall_s": []}
+        diag_on = tel is not None and tel.cfg.diag_every > 0
+        if diag_on and mesh is not None:
+            raise SystemExit(
+                "--diag-every and a mesh conflict: the obs gap estimator is "
+                "single-device only (docs/architecture.md#limits) — drop "
+                "--diag-every or pass --shard off"
+            )
+        phased_step = step_diag = None
+        if mesh is None:
+            from repro.fl.engine import RoundEngine
 
-            phased_step = make_phased_step(eng, tel)
+            eng = RoundEngine(model.loss, fl, server_opt)
+            if tel is not None and tel.cfg.phases and eng.memory == "vmap":
+                from repro.obs.phased import make_phased_step
+
+                phased_step = make_phased_step(eng, tel)
+            else:
+                step = jax.jit(eng.make_step())
+                if diag_on:
+                    step_diag = jax.jit(eng.make_step(True))
         else:
-            step = jax.jit(eng.make_step())
-            if diag_on:
-                step_diag = jax.jit(eng.make_step(True))
-    else:
-        if server_opt is not None:
-            raise SystemExit(
-                "--server-opt and a mesh conflict: the shard_map round has "
-                "no server-optimizer stage (docs/architecture.md#limits) — "
-                "drop --server-opt or pass --shard off"
-            )
-        step = jax.jit(make_engine(model.loss, fl, mesh=mesh))
-    w = client_weights(fl)
-    rng = np.random.default_rng(0)
-    total_bits = 0
-    # stateful samplers (cyclic/threshold): carry their SamplerState round
-    # to round, exactly like the sim driver does.
-    from repro.core.sampling import init_sampler_state, is_stateful
+            if server_opt is not None:
+                raise SystemExit(
+                    "--server-opt and a mesh conflict: the shard_map round has "
+                    "no server-optimizer stage (docs/architecture.md#limits) — "
+                    "drop --server-opt or pass --shard off"
+                )
+            step = jax.jit(make_engine(model.loss, fl, mesh=mesh))
+        w = client_weights(fl)
+        rng = np.random.default_rng(0)
+        total_bits = 0
+        # stateful samplers (cyclic/threshold): carry their SamplerState round
+        # to round, exactly like the sim driver does.
+        from repro.core.sampling import init_sampler_state, is_stateful
 
-    samp = init_sampler_state() if is_stateful(fl.sampler) else None
+        samp = init_sampler_state() if is_stateful(fl.sampler) else None
 
     # full-state checkpoint/resume: the arch trajectory is defined by
     # (params, server-opt state, the synthetic-batch RNG stream, the
@@ -538,8 +544,13 @@ def main(argv=None):
     for k in range(k0, args.rounds):
         if tel is not None:
             tel.round_start(k)
-        batch = synthetic_token_batch(rng, cfg, fl.n_clients, fl.local_steps,
-                                      args.batch, args.seq)
+        # spans as in the sim driver: `data` and `round` record into the
+        # telemetry when it is on (and then wait on their block target);
+        # `compile` and `ledger` only annotate
+        with obs_span("data", tel) as s:
+            batch = synthetic_token_batch(rng, cfg, fl.n_clients, fl.local_steps,
+                                          args.batch, args.seq)
+            s.block(batch)
         t0 = time.perf_counter()
         kk = jax.random.fold_in(key, k)
         diag = diag_on and tel.want_gap(k)
@@ -553,29 +564,40 @@ def main(argv=None):
                 params, opt_state, batch, w, kk, trace, samp, diag=diag
             )
         else:
-            params, opt_state, m = (step_diag if diag else step)(
-                params, opt_state, batch, w, kk, trace, samp
-            )
+            with obs_span("round", tel) as s:
+                # the run's first dispatch traces, lowers and compiles the
+                # step (or loads it from the persistent cache)
+                with obs_span("compile") if k == k0 else contextlib.nullcontext():
+                    params, opt_state, m = (step_diag if diag else step)(
+                        params, opt_state, batch, w, kk, trace, samp
+                    )
+                s.block(m.loss)
         if samp is not None:
             samp = m.sampler_state
-        if state is not None:
-            sys_col = (f"sel {int(m.selected_clients)} "
-                       f"miss {int(m.deadline_misses)} drop {int(m.dropouts)} ")
-        loss = float(m.loss)
-        total_bits += round_bits(fl, dim, m.mask)
-        wall_s = time.perf_counter() - t0
-        history["loss"].append(loss)
-        history["wall_s"].append(wall_s)
-        if diag:
-            tel.record_gap(k, float(m.gap.gap_sq), float(m.gap.full_sq))
-        if tel is not None:
-            tel.record_round(
-                k, loss=loss, sent_clients=int(m.sent_clients),
-                wall_ms=wall_s * 1e3, uplink_bits_total=int(total_bits),
-            )
-        print(f"[round {k:3d}] loss {loss:.4f} alpha {float(m.alpha):.3f} "
-              f"gamma {float(m.gamma):.3f} sent {int(m.sent_clients)}/{fl.n_clients} "
-              f"{sys_col}bits {total_bits/1e9:.2f}G ({wall_s:.1f}s)")
+        # the round's reads of its metrics, the only syncs of the loop
+        with obs_span("ledger"):
+            if state is not None:
+                sys_col = (f"sel {int(m.selected_clients)} "
+                           f"miss {int(m.deadline_misses)} drop {int(m.dropouts)} ")
+            loss = float(m.loss)
+            total_bits += round_bits(fl, dim, m.mask)
+            wall_s = time.perf_counter() - t0
+            history["loss"].append(loss)
+            history["wall_s"].append(wall_s)
+            if diag:
+                tel.record_gap(k, float(m.gap.gap_sq), float(m.gap.full_sq))
+            if tel is not None:
+                # read only with telemetry on: a client whose update norm is
+                # NaN or inf would poison the whole cohort's sampling plan
+                norms = np.asarray(jax.device_get(m.norms))
+                tel.record_round(
+                    k, loss=loss, sent_clients=int(m.sent_clients),
+                    wall_ms=wall_s * 1e3, uplink_bits_total=int(total_bits),
+                    nonfinite_norms=int(np.sum(~np.isfinite(norms))),
+                )
+            print(f"[round {k:3d}] loss {loss:.4f} alpha {float(m.alpha):.3f} "
+                  f"gamma {float(m.gamma):.3f} sent {int(m.sent_clients)}/{fl.n_clients} "
+                  f"{sys_col}bits {total_bits/1e9:.2f}G ({wall_s:.1f}s)")
         if args.checkpoint and (
             (k + 1) % args.ckpt_every == 0 or k + 1 == args.rounds
         ):

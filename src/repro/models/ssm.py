@@ -63,6 +63,19 @@ def _split(zxbcdt, cfg: ModelConfig):
     return z, xbc, dt
 
 
+def _masked_decay(seg, tri):
+    """``exp(seg)`` on and below the diagonal, 0 above it.
+
+    ``seg[s, t] = sum_{t<r<=s} dt_r A`` is <= 0 where ``t <= s``, but above
+    the diagonal it is the sum with its sign flipped, >= 0, and its
+    exponent overflows once ``sum dt |A|`` over a chunk passes ~88.7.  So the
+    mask goes in before the exponent: ``where(tri, exp(seg), 0)`` would
+    still give the right values, yet its gradient multiplies the masked
+    entries' zero cotangent by ``exp(seg) = inf``, which is NaN.
+    """
+    return jnp.exp(jnp.where(tri, seg, -jnp.inf))
+
+
 def _gated_norm(y, z, scale, eps=1e-6):
     y = y * jax.nn.silu(z.astype(jnp.float32))
     ms = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
@@ -97,6 +110,8 @@ def apply_mamba2(params, x, cfg: ModelConfig):
         valid = (jnp.arange(seq) < true_seq)[None, :, None]
         dt = jnp.where(valid, dt, 0.0)
     a = -jnp.exp(params["A_log"].astype(jnp.float32))                 # (H,)
+    # da <= 0, so a_cs (its running sum in a chunk) falls: exp(a_cs),
+    # exp(a_tot - a_cs) and exp(a_tot) below all take arguments <= 0
     da = dt * a                                                        # (B,S,H)
 
     # chunk
@@ -114,16 +129,19 @@ def apply_mamba2(params, x, cfg: ModelConfig):
         def chunk_step(state, inp):
             x_i, b_i, c_i, dt_i, da_i = inp  # (B,Q,...) for this chunk
             a_cs = jnp.cumsum(da_i, axis=1)                       # (B,Q,H)
-            seg = a_cs[:, :, None, :] - a_cs[:, None, :, :]       # (B,Q,Q,H)
-            decay = jnp.where(tri[None, :, :, None], jnp.exp(seg), 0.0)
-            cb = jnp.einsum("bsn,btn->bst", c_i, b_i)
-            att = cb[..., None] * decay * dt_i[:, None, :, :]
-            y_diag = jnp.einsum("bsth,bthp->bshp", att, x_i)
-            y_off = jnp.einsum("btn,bth,bhpn->bthp", c_i, jnp.exp(a_cs), state)
-            a_tot = a_cs[:, -1, :]
-            decay_out = jnp.exp(a_tot[:, None, :] - a_cs)
-            s_chunk = jnp.einsum("bth,btn,bthp->bhpn", decay_out * dt_i, b_i, x_i)
-            new_state = state * jnp.exp(a_tot)[:, :, None, None] + s_chunk
+            with jax.named_scope("ssd_intra"):
+                seg = a_cs[:, :, None, :] - a_cs[:, None, :, :]   # (B,Q,Q,H)
+                decay = _masked_decay(seg, tri[None, :, :, None])
+                cb = jnp.einsum("bsn,btn->bst", c_i, b_i)
+                att = cb[..., None] * decay * dt_i[:, None, :, :]
+                y_diag = jnp.einsum("bsth,bthp->bshp", att, x_i)
+            with jax.named_scope("ssd_inter"):
+                y_off = jnp.einsum("btn,bth,bhpn->bthp", c_i, jnp.exp(a_cs), state)
+            with jax.named_scope("ssd_states"):
+                a_tot = a_cs[:, -1, :]
+                decay_out = jnp.exp(a_tot[:, None, :] - a_cs)
+                s_chunk = jnp.einsum("bth,btn,bthp->bhpn", decay_out * dt_i, b_i, x_i)
+                new_state = state * jnp.exp(a_tot)[:, :, None, None] + s_chunk
             return new_state, y_diag + y_off
 
         init = jnp.zeros((bsz, nheads, p, n), jnp.float32)
@@ -146,38 +164,41 @@ def apply_mamba2(params, x, cfg: ModelConfig):
     a_cs = jnp.cumsum(da_c, axis=2)                                   # (B,NC,Q,H)
 
     # intra-chunk (quadratic within chunk)
-    seg = a_cs[:, :, :, None, :] - a_cs[:, :, None, :, :]             # (B,NC,Q,Q,H)
-    tri = jnp.tril(jnp.ones((q, q), bool))
-    decay = jnp.where(tri[None, None, :, :, None], jnp.exp(seg), 0.0)
-    cb = jnp.einsum("bcsn,bctn->bcst", c_c, b_c)                      # (B,NC,Q,Q)
-    att = cb[..., None] * decay * dt_c[:, :, None, :, :]              # (B,NC,Q,Q,H)
-    y_diag = jnp.einsum("bcsth,bcthp->bcshp", att, xs_c)
+    with jax.named_scope("ssd_intra"):
+        seg = a_cs[:, :, :, None, :] - a_cs[:, :, None, :, :]         # (B,NC,Q,Q,H)
+        tri = jnp.tril(jnp.ones((q, q), bool))
+        decay = _masked_decay(seg, tri[None, None, :, :, None])
+        cb = jnp.einsum("bcsn,bctn->bcst", c_c, b_c)                  # (B,NC,Q,Q)
+        att = cb[..., None] * decay * dt_c[:, :, None, :, :]          # (B,NC,Q,Q,H)
+        y_diag = jnp.einsum("bcsth,bcthp->bcshp", att, xs_c)
 
-    # chunk states: S_c = sum_t exp(a_total - a_cs[t]) dt[t] B_t (x) x_t
-    a_tot = a_cs[:, :, -1, :]                                         # (B,NC,H)
-    decay_out = jnp.exp(a_tot[:, :, None, :] - a_cs)                  # (B,NC,Q,H)
-    s_chunk = jnp.einsum(
-        "bcth,bctn,bcthp->bchpn", decay_out * dt_c, b_c, xs_c
-    )                                                                  # (B,NC,H,P,N)
+    with jax.named_scope("ssd_states"):
+        # chunk states: S_c = sum_t exp(a_total - a_cs[t]) dt[t] B_t (x) x_t
+        a_tot = a_cs[:, :, -1, :]                                     # (B,NC,H)
+        decay_out = jnp.exp(a_tot[:, :, None, :] - a_cs)              # (B,NC,Q,H)
+        s_chunk = jnp.einsum(
+            "bcth,bctn,bcthp->bchpn", decay_out * dt_c, b_c, xs_c
+        )                                                              # (B,NC,H,P,N)
 
-    # inter-chunk recurrence
-    def scan_fn(state, inp):
-        s_c, atot = inp
-        new = state * jnp.exp(atot)[:, :, None, None] + s_c
-        return new, state  # emit the state *entering* this chunk
+        # inter-chunk recurrence
+        def scan_fn(state, inp):
+            s_c, atot = inp
+            new = state * jnp.exp(atot)[:, :, None, None] + s_c
+            return new, state  # emit the state *entering* this chunk
 
-    init = jnp.zeros((bsz, nheads, p, n), jnp.float32)
-    final_state, states_in = jax.lax.scan(
-        scan_fn,
-        init,
-        (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(a_tot, 1, 0)),
-    )
-    states_in = jnp.moveaxis(states_in, 0, 1)                          # (B,NC,H,P,N)
+        init = jnp.zeros((bsz, nheads, p, n), jnp.float32)
+        final_state, states_in = jax.lax.scan(
+            scan_fn,
+            init,
+            (jnp.moveaxis(s_chunk, 1, 0), jnp.moveaxis(a_tot, 1, 0)),
+        )
+        states_in = jnp.moveaxis(states_in, 0, 1)                      # (B,NC,H,P,N)
 
     # inter-chunk contribution
-    y_off = jnp.einsum(
-        "bctn,bcth,bchpn->bcthp", c_c, jnp.exp(a_cs), states_in
-    )
+    with jax.named_scope("ssd_inter"):
+        y_off = jnp.einsum(
+            "bctn,bcth,bchpn->bcthp", c_c, jnp.exp(a_cs), states_in
+        )
     y = (y_diag + y_off).reshape(bsz, seq, nheads, p)
     y = y + params["D"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(bsz, seq, d_in)

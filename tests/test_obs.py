@@ -169,6 +169,41 @@ def test_telemetry_off_spans_in_the_trace(small_ds, tmp_path, mode, units):
                      + ["round"] * (units - 1) + ["ledger"])
 
 
+def test_arch_loop_spans_in_the_trace(tmp_path, monkeypatch):
+    """``launch/train.py``'s arch loop names its host work as the sim
+    driver does: ``setup`` once, then per round ``data``, ``round`` (the
+    first holding the one ``compile``) and ``ledger``, in that order; its
+    round events count the clients whose update norm is not finite."""
+    from repro.launch import train
+
+    # train.main turns the persistent compile cache on unless this variable
+    # is set; set after jax read it, it keeps this test process uncached
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
+    events = str(tmp_path / "events.jsonl")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        train.main(["--arch", "mamba2-130m-reduced", "--rounds", "2", "--clients", "4",
+                    "--expected", "2", "--batch", "1", "--seq", "32",
+                    "--engine", "scan", "--scan-group", "2", "--cache-groups", "2",
+                    "--agg-backend", "pallas", "--obs-jsonl", events])
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path / "trace"))
+    counts = {}
+    for sp_ in spans:
+        counts[sp_[2]] = counts.get(sp_[2], 0) + 1
+    assert counts == {"setup": 1, "data": 2, "round": 2, "compile": 1, "ledger": 2}
+    (compile_,) = [s for s in spans if s[2] == "compile"]
+    first_round = next(s for s in spans if s[2] == "round")
+    assert _inside(first_round, compile_)
+    order = [s[2] for s in spans if s[2] != "compile"]
+    assert order == ["setup"] + ["data", "round", "ledger"] * 2
+    rounds = [e for e in read_events(events) if e["kind"] == "round"]
+    assert [e["nonfinite_norms"] for e in rounds] == [0, 0]
+
+
 def test_phase_contract_names():
     # the contract tuple the endpoint/docs key on — order is the span
     # *naming* contract, not execution order (docs/observability.md)
