@@ -54,7 +54,9 @@ reported separately because the paper's x-axis excludes broadcast,
 footnote 5) — serialised as a schema-3 JSON artifact (``validate_ledger`` is
 the contract both the tests and the ``bench_sim --smoke`` CI gate assert;
 schema 1 lacked the system-layer series, schema 2 lacked ``wall_ms`` and the
-gap series).
+gap series).  The round loops never read the ledger's values back: after
+the last round (and at each checkpoint, for the rounds since the previous
+one) :func:`fetch_ledger` takes them to the host in one transfer.
 
 An ``obs`` argument (:class:`repro.obs.ObsConfig`, or a live
 :class:`repro.obs.Telemetry` when the caller wants the endpoint to outlive
@@ -152,6 +154,58 @@ LEDGER_SERIES = (
 # sparse per-diagnostic-round series (schema 3; empty when the run had no
 # obs gap estimator) — all four the same length, indexed by gap_rounds
 GAP_SERIES = ("gap_rounds", "gap_sq", "gap_full_sq", "gap_ratio")
+
+# the RoundMetrics fields the ledger reads back from the device
+LEDGER_FIELDS = (
+    "loss", "alpha", "gamma", "sent_clients", "expected_clients",
+    "selected_clients", "deadline_misses", "dropouts", "mask", "norms",
+)
+
+# per-round RoundMetrics are stacked on the device this many rounds at a
+# time before the ledger reads them (the last chunk padded to it), so every
+# call of a process shares one compile of the stacking program
+LEDGER_CHUNK = 64
+
+
+@jax.jit
+def _stack_rounds(rows):
+    """``LEDGER_CHUNK`` rounds' ledger fields, each stacked along axis 0."""
+    return tuple(jnp.stack(col) for col in zip(*rows))
+
+
+def fetch_ledger(entries, extra=()):
+    """The ``LEDGER_FIELDS`` of ``entries`` on the host, plus ``extra``.
+
+    ``entries`` are RoundMetrics of one round each, or of a block of rounds
+    stacked along axis 0 (scan mode); the result holds one host array per
+    field, every entry's rounds stacked along axis 0 in order.  A device
+    read costs about the same whatever its size, so one-round entries are
+    first stacked on the device ``LEDGER_CHUNK`` at a time (the last chunk
+    padded with its final entry, trimmed here), and everything — chunks,
+    blocks and ``extra`` — goes to the host in ONE ``jax.device_get``,
+    which starts every copy before it waits on any.
+    """
+    parts, run = [], []   # (fields, rounds) per device copy; pending rounds
+
+    def flush():
+        for i in range(0, len(run), LEDGER_CHUNK):
+            part = run[i:i + LEDGER_CHUNK]
+            pad = [part[-1]] * (LEDGER_CHUNK - len(part))
+            parts.append((_stack_rounds(tuple(part + pad)), len(part)))
+        run.clear()
+
+    for m in entries:
+        fields = tuple(getattr(m, name) for name in LEDGER_FIELDS)
+        if np.ndim(m.loss) == 0:
+            run.append(fields)
+        else:
+            flush()
+            parts.append((fields, len(m.loss)))
+    flush()
+    host, extra = jax.device_get(([f for f, _ in parts], list(extra)))
+    cols = [np.concatenate([h[j][:n] for h, (_, n) in zip(host, parts)], 0)
+            for j in range(len(LEDGER_FIELDS))]
+    return cols, extra
 
 
 @dataclass
@@ -469,8 +523,8 @@ def run_simulation(
         with obs_span("compile"):
             return step(*args)
 
-    dev_metrics = []          # device-side RoundMetrics (stacked blocks in scan)
-    dev_evals = []            # (round, device scalar)
+    dev_metrics = []          # device-side RoundMetrics not read yet (blocks in scan)
+    dev_evals = []            # (round, device scalar; on the host once read)
     wall_ms = []              # per-round wall (monotonic clock; THIS process)
     gap_records = []          # (round, gap_sq, full_sq) on the diag_every grid
     tel_up = tel_down = tel_miss = tel_drop = 0   # live endpoint counters
@@ -522,9 +576,7 @@ def run_simulation(
         # after round k: on the every-grid, and always after the final round
         return ck is not None and ((k + 1) % ck.every == 0 or k + 1 == rounds)
 
-    def rows(name):
-        vals = [np.asarray(getattr(m, name)) for m in dev_metrics]
-        return np.concatenate(vals, 0) if mode == "scan" else np.stack(vals, 0)
+    host_rows = [[] for _ in LEDGER_FIELDS]   # per field: the rounds read so far
 
     def splice_series():
         """Full-run per-round series plus (done, n) mask/norm arrays.
@@ -534,12 +586,19 @@ def run_simulation(
         ``float()``/``int()`` calls either way — so a spliced ledger is
         byte-identical to the uninterrupted run's, not merely close.
         """
-        losses, alphas, gammas = rows("loss"), rows("alpha"), rows("gamma")
-        sents, expected = rows("sent_clients"), rows("expected_clients")
-        selected = rows("selected_clients")
-        misses, drops = rows("deadline_misses"), rows("dropouts")
-        masks_l = rows("mask").astype(bool)
-        norms_l = rows("norms").astype(np.float32)
+        if dev_metrics:
+            # the rounds not read yet (and every eval) in one device read, so
+            # each checkpoint reads only the rounds since the previous one
+            with obs_span("ledger_read"):
+                cols, evals = fetch_ledger(dev_metrics, [v for _, v in dev_evals])
+            for col, new in zip(host_rows, cols):
+                col.append(new)
+            dev_evals[:] = [(k, v) for (k, _), v in zip(dev_evals, evals)]
+            dev_metrics.clear()
+        (losses, alphas, gammas, sents, expected, selected, misses, drops,
+         masks_l, norms_l) = (np.concatenate(col, 0) for col in host_rows)
+        masks_l = masks_l.astype(bool)
+        norms_l = norms_l.astype(np.float32)
         ser = {name: list(tail[name]) for name in LEDGER_SERIES}
         up_total = ser["uplink_bits"][-1] if ser["uplink_bits"] else 0
         down_total = ser["downlink_bits"][-1] if ser["downlink_bits"] else 0
@@ -872,8 +931,9 @@ def run_simulation(
         jax.block_until_ready(dev_metrics[-1].loss)
     t_end = time.perf_counter()
 
-    # the ledger: the per-round device reads and the host arithmetic over
-    # them, after the final sync (an annotation only; nothing records it)
+    # the ledger: the one device read of the rounds not read yet (nested
+    # `ledger_read`) and the host arithmetic over them, after the final
+    # sync (annotations only; nothing records them)
     with obs_span("ledger"):
         ledger = SimLedger(
             mode=mode,

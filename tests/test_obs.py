@@ -130,10 +130,10 @@ def _inside(outer, inner):
 def test_telemetry_off_spans_in_the_trace(small_ds, tmp_path, mode, units):
     """With telemetry off the driver still names its host work in the
     profiler trace, on the calling thread and properly nested: the call's
-    pool build and upload, set-up, first sync and ledger once; ``data``
-    (holding one ``plan`` and one ``gather``) and ``round`` once per round
-    (prefetch) or per block (scan); one ``compile`` inside the first
-    ``round``."""
+    pool build and upload, set-up, first sync and ledger once, the ledger
+    holding its one device read ``ledger_read``; ``data`` (holding one
+    ``plan`` and one ``gather``) and ``round`` once per round (prefetch) or
+    per block (scan); one ``compile`` inside the first ``round``."""
     init, loss, _ = mlp_classifier(small_ds.input_dim, small_ds.num_classes,
                                    hidden=8)
     fl = FLConfig(n_clients=4, expected_clients=2, local_steps=2, lr_local=0.1)
@@ -151,8 +151,8 @@ def test_telemetry_off_spans_in_the_trace(small_ds, tmp_path, mode, units):
         by_name.setdefault(sp_[2], []).append(sp_)
     counts = {n: len(v) for n, v in by_name.items()}
     assert counts == {"setup": 1, "pool_build": 1, "pool_upload": 1, "ledger": 1,
-                      "first_sync": 1, "data": units, "plan": units,
-                      "gather": units, "round": units, "compile": 1}
+                      "ledger_read": 1, "first_sync": 1, "data": units,
+                      "plan": units, "gather": units, "round": units, "compile": 1}
     # spans of one thread nest: any two are disjoint or one holds the other
     for i, a in enumerate(spans):
         for b in spans[i + 1:]:
@@ -161,6 +161,7 @@ def test_telemetry_off_spans_in_the_trace(small_ds, tmp_path, mode, units):
         inner = [s for s in spans if s is not d and _inside(d, s)]
         assert sorted(s[2] for s in inner) == ["gather", "plan"]
     assert _inside(by_name["round"][0], by_name["compile"][0])
+    assert _inside(by_name["ledger"][0], by_name["ledger_read"][0])
     # set-up, pool, rounds and ledger follow one another in the call; the
     # first sync ends the first round
     order = [s[2] for s in spans if s[2] in ("setup", "pool_build", "pool_upload",

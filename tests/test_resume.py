@@ -37,7 +37,7 @@ from repro.data import femnist_like
 from repro.models.simple import mlp_classifier
 from repro.optim import sgd
 from repro.sim import run_simulation
-from repro.sim.driver import build_client_mesh
+from repro.sim.driver import LEDGER_CHUNK, build_client_mesh
 from repro.sim.pool import SystemConfig
 
 MODES = ("host", "prefetch", "scan")
@@ -175,28 +175,37 @@ def _run(ds, rounds, mode, fl_kw, system, opt_name, **kw):
     )
 
 
+# (variant, rounds, checkpoint every, resumed step) per case.  "-long" runs
+# past a whole chunk of the ledger's device read (LEDGER_CHUNK rounds), with
+# the checkpoint grid and the resumed step inside chunks
+CASES = {v: (v, 7, 4, 4) for v in VARIANTS}
+CASES["threshold+markov-long"] = ("threshold+markov", LEDGER_CHUNK + 3, 5, 65)
+
+
 @pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("variant", sorted(VARIANTS))
+@pytest.mark.parametrize("variant", sorted(CASES))
 def test_resume_parity(small_ds, tmp_path, mode, variant):
     """The tentpole gate: (a) checkpointing must not perturb the run, and
     (b) a run resumed from an INTERMEDIATE checkpoint finishes with bitwise
     params and a byte-identical ledger minus wall-clock.  ckpt_every=4 sits
     off the rounds_per_scan=3 grid on purpose, so scan mode exercises the
     checkpoint-boundary block alignment (and the eval_every=3 grid composes
-    with both)."""
-    fl_kw, system, opt = VARIANTS[variant]
-    rounds = 7
+    with both).  The ``-long`` case splices the ledger's chunked device read
+    across a chunk boundary, checkpointing every 5 rounds."""
+    base, rounds, every, step = CASES[variant]
+    fl_kw, system, opt = VARIANTS[base]
     p_ref, led_ref = _run(small_ds, rounds, mode, fl_kw, system, opt)
     ref = _strip_timing(led_ref.to_json())
     d = str(tmp_path / "ck")
-    _, led_ck = _run(small_ds, rounds, mode, fl_kw, system, opt,
-                     checkpoint=CheckpointConfig(d, every=4))
+    ck = CheckpointConfig(d, every=every)
+    _, led_ck = _run(small_ds, rounds, mode, fl_kw, system, opt, checkpoint=ck)
     # (a) writing checkpoints changed nothing but wall-clock
     assert _strip_timing(led_ck.to_json()) == ref
-    assert available_steps(d) == [4, 7]
+    grid = list(range(every, rounds, every)) + [rounds]
+    assert available_steps(d) == grid[-ck.keep:]
     # (b) resume from the intermediate (NOT final) step, explicitly pinned
     p_res, led_res = _run(small_ds, rounds, mode, fl_kw, system, opt,
-                          resume=os.path.join(d, "step-00000004"))
+                          resume=os.path.join(d, f"step-{step:08d}"))
     assert _strip_timing(led_res.to_json()) == ref
     for a, b in zip(jax.tree_util.tree_leaves(p_ref),
                     jax.tree_util.tree_leaves(p_res)):

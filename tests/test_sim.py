@@ -5,6 +5,7 @@ regression, the scenario-grid smoke, the schema-3 ledger contract, and the
 client-state layer's determinism regression (same seed => byte-identical
 straggler-cell ledger JSON in all three driver modes)."""
 
+import contextlib
 import json
 import os
 import subprocess
@@ -21,6 +22,7 @@ from repro.fl.engine import RoundEngine
 from repro.fl.round import client_weights
 from repro.fl.trainer import run_training
 from repro.models.simple import mlp_classifier
+from repro.sim import driver as drv
 from repro.sim import pool as pool_mod
 from repro.sim import (
     ClientPool,
@@ -31,6 +33,7 @@ from repro.sim import (
     run_simulation,
     validate_ledger,
 )
+from repro.sim.driver import LEDGER_CHUNK
 from repro.sim.pool import device_shape
 
 MODES = ("host", "prefetch", "scan")
@@ -401,6 +404,89 @@ def test_straggler_cell_deterministic_across_modes():
     assert sum(doc["metrics"]["over_selected"]) > 0
     assert all(v >= 0 for v in doc["metrics"]["deadline_misses"])
     assert all(v >= 0 for v in doc["metrics"]["dropouts"])
+
+
+def test_straggler_cell_identical_across_modes_past_a_ledger_chunk():
+    """The cross-mode identity above over more rounds than one chunk of the
+    ledger's device read (LEDGER_CHUNK), ending mid-chunk: the chunked read
+    (host, prefetch) and the stacked blocks (scan) splice byte-identical
+    ledgers."""
+    rounds = LEDGER_CHUNK + 3
+    docs = {}
+    for mode in MODES:
+        _, led = run_scenario("femnist1-fedavg-aocs-straggler", reduced=True,
+                              mode=mode, rounds=rounds, rounds_per_scan=8, seed=11)
+        validate_ledger(led.to_json())
+        assert len(led.loss) == len(led.masks) == rounds
+        docs[mode] = json.dumps(_strip_timing(led.to_json(include_masks=True),
+                                              mode_identity=True), sort_keys=True)
+    assert docs["prefetch"] == docs["host"]
+    assert docs["scan"] == docs["host"]
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_ledger_device_reads_do_not_grow_with_rounds(small_ds, mode, monkeypatch):
+    """The ledger reads the device once a call, however many rounds it ran:
+    one ``ledger_read`` span holding one ``jax.device_get``, at 5 rounds and
+    at 2 x LEDGER_CHUNK + 3, and no other device read (``np.asarray``,
+    ``float()``) inside the ``ledger`` span."""
+    from jax._src.array import ArrayImpl
+
+    open_spans, seen = [], {}
+    real_get, real_span, real_value = jax.device_get, drv.obs_span, ArrayImpl._value
+    real_asarray = np.asarray
+
+    def device_get(x):
+        if "ledger" in open_spans:
+            seen["gets"] += 1
+        open_spans.append("device_get")
+        try:
+            return real_get(x)
+        finally:
+            open_spans.pop()
+
+    @contextlib.contextmanager
+    def span(name, sink=None):
+        seen[name] = seen.get(name, 0) + 1
+        open_spans.append(name)
+        try:
+            with real_span(name, sink) as s:
+                yield s
+        finally:
+            open_spans.pop()
+
+    def loose(x):
+        if (isinstance(x, jax.Array) and "ledger" in open_spans
+                and "device_get" not in open_spans):
+            seen["loose"] += 1
+
+    def asarray(x, *a, **kw):
+        loose(x)   # on the CPU np.asarray reads a jax.Array without _value
+        return real_asarray(x, *a, **kw)
+
+    def value(self):
+        loose(self)
+        return real_value.fget(self)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    monkeypatch.setattr(drv, "obs_span", span)
+    monkeypatch.setattr(np, "asarray", asarray)
+    monkeypatch.setattr(ArrayImpl, "_value", property(value))
+    init, loss, acc = _model(small_ds)
+    fl = FLConfig(n_clients=8, expected_clients=3, local_steps=1, lr_local=0.1)
+    ev = {"x": jnp.zeros((4, small_ds.input_dim)), "y": jnp.zeros((4,), jnp.int32)}
+    for rounds in (5, 2 * LEDGER_CHUNK + 3):
+        seen.clear()
+        seen.update(gets=0, loose=0)
+        _, led = run_simulation(
+            small_ds, init, loss, fl, rounds, batch_size=4, mode=mode,
+            rounds_per_scan=4, seed=0, eval_fn=jax.jit(acc), eval_batch=ev,
+            eval_every=50,
+        )
+        assert len(led.loss) == len(led.masks) == rounds
+        assert len(led.acc) == len(range(0, rounds, 50)) + 1
+        assert seen["ledger"] == seen["ledger_read"] == seen["gets"] == 1, (rounds, seen)
+        assert seen["loose"] == 0, (rounds, seen)
 
 
 def test_straggler_shard_cell_matches_unsharded():
