@@ -1,22 +1,28 @@
 """Multi-round simulation driver: the paper's Sec. 4 evaluation loop as a
 subsystem, with a structured metrics ledger and versioned JSON artifacts.
 
-``run_simulation`` replaces the trainer's inner loop with three execution
-modes over the same round semantics:
+``run_simulation`` replaces the trainer's inner loop with ONE loop over
+blocks of rounds, in three execution modes over the same round semantics.
+A mode supplies only how a block is drawn and how it is run:
 
-* ``'host'``     — the legacy baseline: numpy batch assembly + upload every
-  round, synchronous with the jitted step (kept as the benchmark reference);
-* ``'prefetch'`` — the :class:`repro.sim.pool.ClientPool` pipeline: round
-  k+1's cohort plan is drawn and its device gather dispatched while round
-  k's jitted step is still running (double-buffered), and the loop never
-  blocks on device results until the end;
-* ``'scan'``     — scan-over-rounds fast path for fully device-resident
-  pools: blocks of ``rounds_per_scan`` rounds run inside one jitted
-  ``lax.scan`` (cohort gather in the scan body), removing per-round dispatch
-  entirely.  Eval (when requested) keeps the ``eval_every`` grid: block
-  boundaries are aligned so every eval round ends a block, and the ledger's
-  ``acc_rounds`` are identical across all three modes (regression-gated in
-  tests/test_sim.py — an earlier version evaluated once per block only).
+* ``'host'``     — the legacy baseline, one-round blocks: numpy batch
+  assembly + upload every round, synchronous with the jitted step (kept as
+  the benchmark reference);
+* ``'prefetch'`` — the :class:`repro.sim.pool.ClientPool` pipeline,
+  one-round blocks drawn one ahead: round k+1's cohort plan is drawn and
+  its device gather dispatched while round k's jitted step is still
+  running (double-buffered), and the loop never blocks on device results
+  until the end;
+* ``'scan'``     — scan-over-rounds for fully device-resident pools: blocks
+  of up to ``rounds_per_scan`` rounds run inside one jitted ``lax.scan``
+  (cohort gather in the scan body), removing per-round dispatch entirely.
+
+What follows a block is written once for every mode: the sampler-state
+hand-over, the ledger entry, eval, the first-sync mark, ``wall_ms``, the
+telemetry rows and the checkpoint.  A block ends on the first
+``eval_every`` or checkpoint round it reaches, so the ledger's
+``acc_rounds`` are identical across all three modes (regression-gated in
+tests/test_sim.py).
 
 A ``mesh`` argument switches ``'host'`` and ``'prefetch'`` onto the
 explicit-collective shard_map round (``fl.engine.make_engine(mesh=...)``):
@@ -36,9 +42,9 @@ parity gate in tests/test_sim.py; the batches match bitwise because
 A ``system`` argument (:class:`repro.sim.pool.SystemConfig`) switches on the
 client-state layer: a device-resident :class:`repro.sim.pool.ClientState`
 (Markov availability chains + latency scales over the whole dataset pool) is
-stepped once per round — in the host/prefetch loops as its own jitted step,
-in scan mode inside the ``lax.scan`` carry next to ``(params, opt_state)``
-— and the resulting per-cohort ``AvailabilityTrace`` rides into the round
+stepped once per round — in a one-round block's draw as its own jitted
+step, in scan mode inside the ``lax.scan`` carry next to ``(params,
+opt_state)`` — and the resulting per-cohort ``AvailabilityTrace`` rides into the round
 step, where ``ocs.sampling_plan`` rescales by each client's realized
 inclusion probability.  The state key stream is a disjoint fold of the same
 round keys, so masks stay bitwise identical across all three modes (and the
@@ -54,7 +60,7 @@ reported separately because the paper's x-axis excludes broadcast,
 footnote 5) — serialised as a schema-3 JSON artifact (``validate_ledger`` is
 the contract both the tests and the ``bench_sim --smoke`` CI gate assert;
 schema 1 lacked the system-layer series, schema 2 lacked ``wall_ms`` and the
-gap series).  The round loops never read the ledger's values back: after
+gap series).  The round loop never reads the ledger's values back: after
 the last round (and at each checkpoint, for the rounds since the previous
 one) :func:`fetch_ledger` takes them to the host in one transfer.
 
@@ -66,7 +72,7 @@ the sparse ``gap_*`` ledger series and the endpoint's ``repro_gap_ratio``),
 the JSONL event stream and the live metrics endpoint.  Telemetry changes NO
 round mathematics — masks, norms and params are bitwise what they are with
 ``obs=None`` (gated in tests/test_obs.py) — but it does change *scheduling*:
-the prefetch loop gains a per-round device sync so wall times are honest
+prefetch mode gains a per-round device sync so wall times are honest
 (the observer effect; docs/observability.md).  The gap estimator is
 single-device only (rejected with a mesh); ``ObsConfig.phases`` applies to
 host-mode vmap engines and is ignored elsewhere (scan rounds are timed at
@@ -80,9 +86,10 @@ bare directory path) writes a full-fidelity
 :class:`repro.checkpoint.RoundCheckpoint` after every ``every``-th round —
 params, server-opt state, the pool generator's exact bit-state, the
 ``ClientState`` chains, the ``SamplerState`` carry, the round index, the
-ledger tail and a config fingerprint — atomically, from all three modes
-(scan checkpoints at block boundaries; block spans are aligned to the
-checkpoint grid the same way they align to the eval grid).  ``resume=``
+ledger tail and a config fingerprint — atomically, from all three modes,
+at block ends (which land on the checkpoint grid as on the eval grid),
+holding the RNG and chain state from just before the next block's draw.
+``resume=``
 restores one and continues at the saved round: the finished run's params
 are **bitwise identical** and its ledger JSON **byte-identical** (minus the
 wall-clock fields) to the uninterrupted run's, in every mode, with or
@@ -258,25 +265,8 @@ class SimLedger:
             "mode": self.mode,
             "fl": self.fl,
             "workload": self.workload,
-            "metrics": {
-                "loss": self.loss,
-                "alpha": self.alpha,
-                "gamma": self.gamma,
-                "sent": self.sent,
-                "expected_clients": self.expected_clients,
-                "over_selected": self.over_selected,
-                "deadline_misses": self.deadline_misses,
-                "dropouts": self.dropouts,
-                "uplink_bits": self.uplink_bits,
-                "downlink_bits": self.downlink_bits,
-                "wall_ms": self.wall_ms,
-                "gap_rounds": self.gap_rounds,
-                "gap_sq": self.gap_sq,
-                "gap_full_sq": self.gap_full_sq,
-                "gap_ratio": self.gap_ratio,
-                "acc_rounds": self.acc_rounds,
-                "acc": self.acc,
-            },
+            "metrics": {name: getattr(self, name) for name in
+                        LEDGER_SERIES + GAP_SERIES + ("acc_rounds", "acc")},
             "wall_s": self.wall_s,
             "rounds_per_sec": self.rounds_per_sec,
         }
@@ -388,7 +378,7 @@ def run_simulation(
 ) -> tuple:
     """Run ``rounds`` communication rounds; returns ``(params, SimLedger)``.
 
-    One driver, three execution modes (module docstring); all modes draw the
+    One block loop, three execution modes (module docstring); all modes draw the
     cohort (``rng.choice`` without replacement), the per-client example
     permutations and the per-round keys (``fold_in(key, 1000 + k)``) in the
     legacy trainer's exact order, so the per-round participation masks are
@@ -511,18 +501,6 @@ def run_simulation(
     def want_eval(k):
         return eval_fn is not None and (k % eval_every == 0 or k == rounds - 1)
 
-    compiled = False
-
-    def dispatch(step, *args):
-        # the call's first dispatch of its step traces, lowers and compiles
-        # it (or loads it from the persistent cache): a nested "compile" span
-        nonlocal compiled
-        if compiled:
-            return step(*args)
-        compiled = True
-        with obs_span("compile"):
-            return step(*args)
-
     dev_metrics = []          # device-side RoundMetrics not read yet (blocks in scan)
     dev_evals = []            # (round, device scalar; on the host once read)
     wall_ms = []              # per-round wall (monotonic clock; THIS process)
@@ -644,9 +622,14 @@ def run_simulation(
         ))
 
     def tel_round(k, metrics, ms_val):
-        # per-round endpoint/event record (telemetry on only).  The mask
-        # pull syncs the device — part of the documented observer effect.
+        # per-round endpoint/event record (telemetry on only), after the
+        # round's gap on the diag_every grid.  The mask pull syncs the
+        # device — part of the documented observer effect.
         nonlocal tel_up, tel_down, tel_miss, tel_drop
+        if diag_on and tel.want_gap(k):
+            gs, fs = float(metrics.gap.gap_sq), float(metrics.gap.full_sq)
+            gap_records.append((k, gs, fs))
+            tel.record_gap(k, gs, fs)
         up, down = round_bits_duplex(fl, dim, np.asarray(metrics.mask))
         tel_up += int(up)
         tel_down += int(down)
@@ -659,11 +642,15 @@ def run_simulation(
             dropouts_total=tel_drop,
         )
 
-    def tel_gap(k, gap):
-        gs, fs = float(gap.gap_sq), float(gap.full_sq)
-        gap_records.append((k, gs, fs))
-        if tel is not None:
-            tel.record_gap(k, gs, fs)
+    def block_end(k, longest):
+        # the last round of the block that starts at round k: at most
+        # `longest` rounds, ending early on the first eval or checkpoint
+        # round it reaches (eval and checkpoint happen after a round's
+        # step, so scan's acc_rounds and checkpoints land on the same
+        # rounds as a one-round block's)
+        last = min(k + longest, rounds) - 1
+        return next((j for j in range(k, last)
+                     if want_eval(j) or need_ckpt(j)), last)
 
     if tel is not None:
         tel.run_start(
@@ -673,181 +660,42 @@ def run_simulation(
         )
     t_start = time.perf_counter()
 
-    if mode == "host":
-        if use_phased:
-            from repro.obs.phased import make_phased_step
-
-            phased_step = make_phased_step(engine, tel)
-        else:
-            round_step = jax.jit(step_factory(), donate_argnums=(0, 1))
-            if diag_on:
-                round_step_diag = jax.jit(
-                    step_factory(True), donate_argnums=(0, 1)
-                )
-        for k in range(k0, rounds):
-            t_round = time.perf_counter()
-            diag = diag_on and tel.want_gap(k)
-            if tel is not None:
-                tel.round_start(k)
-            with obs_span("data", tel) as s:
-                clients = draw_cohort()
-                w = cohort_weights(clients)
-                batch = dataset.sample_round_batches(
-                    rng, clients, fl.local_steps, batch_size, local_epoch
-                )
-                batch = {bk: jnp.asarray(v) for bk, v in batch.items()}
-                s.block(batch)
-            kk = jax.random.fold_in(key, 1000 + k)
-            if state is not None:
-                state, trace = state_step(state, kk, jnp.asarray(clients))
-            else:
-                trace = None
-            if use_phased:
-                params, opt_state, metrics = phased_step(
-                    params, opt_state, batch, w, kk, trace, samp, diag=diag
-                )
-            else:
-                step = round_step_diag if diag else round_step
-                with obs_span("round", tel) as s:
-                    params, opt_state, metrics = dispatch(
-                        step, params, opt_state, batch, w, kk, trace, samp
-                    )
-                    s.block(metrics.loss)
-            if samp is not None:
-                samp = metrics.sampler_state
-            dev_metrics.append(metrics)
-            if want_eval(k):
-                dev_evals.append((k, eval_fn(params, eval_batch)))
-            # the host loop is synchronous by construction (legacy behaviour):
-            # it blocks before assembling the next round's batch.
-            jax.block_until_ready(metrics.loss)
-            if t_first is None:
-                t_first, first_units = time.perf_counter(), 1
-            wall_ms.append((time.perf_counter() - t_round) * 1e3)
-            if diag:
-                tel_gap(k, metrics.gap)
-            if tel is not None:
-                tel_round(k, metrics, wall_ms[-1])
-            if need_ckpt(k):
-                # the host loop draws round k's randomness inside iteration
-                # k, so the live RNG/chain state IS the post-round-k state
-                write_ckpt(k, copy.deepcopy(rng.bit_generator.state),
-                           state, samp)
-
-    elif mode == "prefetch":
+    # ---- per mode: how a block is drawn (its `data` span) and run ----
+    cpool = None
+    if mode != "host":
         cpool = ClientPool(dataset, mesh=mesh, client_axis=fl.client_axis)
-        round_step = jax.jit(step_factory(), donate_argnums=(0, 1))
-        if diag_on:
-            round_step_diag = jax.jit(step_factory(True), donate_argnums=(0, 1))
+    if use_phased:
+        from repro.obs.phased import make_phased_step
 
-        def draw_round(k):
-            # called strictly in round order, so the client-state chain
-            # advances round by round even though round k+1's draw (and its
-            # state step) is dispatched while round k still computes.
-            nonlocal state
-            with obs_span("plan"):
-                clients = draw_cohort()
-                plan = cpool.plan(rng, clients, fl.local_steps, batch_size, local_epoch)
-            kk = jax.random.fold_in(key, 1000 + k)
-            trace = None
-            if state is not None:
-                state, trace = state_step(state, kk, jnp.asarray(plan.clients))
-            return plan, cohort_weights(clients), kk, trace
-
-        with obs_span("data", tel):
-            cur = draw_round(k0)
-            cur_batch = cpool.gather(cur[0])
-        for k in range(k0, rounds):
-            t_round = time.perf_counter()
-            diag = diag_on and tel.want_gap(k)
-            if tel is not None:
-                tel.round_start(k)
-            plan, w, kk, trace = cur
-            batch = cur_batch
-            snap = None
-            if need_ckpt(k) and k + 1 < rounds:
-                # double buffering advances the host RNG and the client-state
-                # chain through round k+1's draw BEFORE round k's checkpoint
-                # is written — snapshot both now, so the resumed process
-                # replays round k+1's draw itself, bit for bit
-                snap = (copy.deepcopy(rng.bit_generator.state), state)
-            if k + 1 < rounds:
-                # double buffering: round k+1's plan is drawn and its gather
-                # dispatched while round k's step is still executing.
-                with obs_span("data", tel):
-                    cur = draw_round(k + 1)
-                    cur_batch = cpool.gather(cur[0])
-            with obs_span("round", tel) as s:
-                params, opt_state, metrics = dispatch(
-                    round_step_diag if diag else round_step,
-                    params, opt_state, batch, w, kk, trace, samp,
-                )
-                s.block(metrics.loss)
-            if samp is not None:
-                samp = metrics.sampler_state
-            dev_metrics.append(metrics)
-            if want_eval(k):
-                dev_evals.append((k, eval_fn(params, eval_batch)))
-            if tel is not None:
-                # OBSERVER EFFECT: telemetry forces a per-round sync so
-                # wall_ms bounds device work — the double-buffered pipeline
-                # stalls here.  Telemetry off keeps the async cadence below.
-                jax.block_until_ready(metrics.loss)
-            if t_first is None:
-                # the only telemetry-off mid-run sync: marks the end of the
-                # compile round, and waits for all queued before it — the
-                # rest of the pool's asynchronous copy to the device too
-                with obs_span("first_sync"):
-                    jax.block_until_ready(metrics.loss)
-                t_first, first_units = time.perf_counter(), 1
-            # telemetry off, this is dispatch cadence, not device time
-            wall_ms.append((time.perf_counter() - t_round) * 1e3)
-            if diag:
-                tel_gap(k, metrics.gap)
-            if tel is not None:
-                tel_round(k, metrics, wall_ms[-1])
-            if need_ckpt(k):
-                # SamplerState is read back AFTER the step, so live `samp`
-                # is correct; RNG/chain come from the pre-prefetch snapshot
-                # (on the final round nothing was prefetched — use live)
-                rng_st, cl_st = snap if snap is not None else (
-                    copy.deepcopy(rng.bit_generator.state), state)
-                write_ckpt(k, rng_st, cl_st, samp)
-
-    else:  # scan-over-rounds
-        cpool = ClientPool(dataset)
+        phased_step = make_phased_step(engine, tel)
+    elif mode != "scan":
+        # the jitted round step, and its diag twin when the gap estimator
+        # is on (the diag step is chosen per round)
+        steps = {d: jax.jit(step_factory(d), donate_argnums=(0, 1))
+                 for d in {False, diag_on}}
+    else:
         # with the gap estimator on, the WHOLE block compiles with the diag
         # step (per-round step selection cannot live inside lax.scan); the
         # ledger still records gaps on the diag_every grid only.
         step_fn = step_factory(diag_on)
-        use_state = state is not None
-        if not use_state:
-            state = ()  # empty carry slot; scanned next to (params, opt_state)
-        use_samp = samp is not None
-        if not use_samp:
-            samp = ()  # empty SamplerState carry slot for stateless samplers
-
         shapes = cpool.example_shapes
 
         def chunk_fn(buffers, params, opt_state, st, sp, clients_s, take_s,
                      smask_s, w_s, keys_s):
+            # the client-state chain and the SamplerState ride in the scan
+            # carry next to (params, opt_state); None when the run has none
             def body(carry, xs):
                 p, o, s, sp = carry
                 c, t, sm, w, kk = xs
                 trace = None
-                if use_state:
-                    # the client-state chain lives in the scan carry: same
-                    # step_client_state, same per-round key fold as the
-                    # host/prefetch jitted state step — bitwise identical.
+                if s is not None:
+                    # same step_client_state, same per-round key fold as the
+                    # one-round modes' jitted state step — bitwise identical
                     s, trace = step_client_state(s, kk, c, system)
                 p, o, m = step_fn(
-                    p, o, gather_batch(buffers, shapes, c, t, sm), w, kk, trace,
-                    sp if use_samp else None,
+                    p, o, gather_batch(buffers, shapes, c, t, sm), w, kk, trace, sp
                 )
-                if use_samp:
-                    # the SamplerState advances in the carry, like the chain
-                    sp = m.sampler_state
-                return (p, o, s, sp), m
+                return (p, o, s, m.sampler_state), m
 
             (params, opt_state, st, sp), ms = jax.lax.scan(
                 body, (params, opt_state, st, sp),
@@ -856,75 +704,143 @@ def run_simulation(
             return params, opt_state, st, sp, ms
 
         chunk = jax.jit(chunk_fn, donate_argnums=(1, 2, 3, 4))
-        done = k0
-        while done < rounds:
-            t_blk = time.perf_counter()
-            if tel is not None:
-                tel.round_start(done)
-            span = min(rounds_per_scan, rounds - done)
-            if ck is not None:
-                # land block ends on the checkpoint grid — same alignment
-                # trick as the eval grid below, composed via min, so every
-                # ckpt_every-th round ENDS a block and can be checkpointed
-                span = min(span, ck.every - done % ck.every)
-            if eval_fn is not None:
-                # keep the eval_every grid: the next eval round must END a
-                # block (eval happens after round k's step), so block spans
-                # shrink to land exactly on it — acc_rounds then match the
-                # host/prefetch modes round for round.
-                nxt = done
-                while not want_eval(nxt):
-                    nxt += 1
-                span = min(span, nxt - done + 1)
-            with obs_span("data", tel):
-                with obs_span("plan"):
-                    plans, w_s, keys_s = [], [], []
-                    for k in range(done, done + span):
-                        clients = draw_cohort()
-                        plans.append(
-                            cpool.plan(rng, clients, fl.local_steps, batch_size, local_epoch)
-                        )
-                        w_s.append(cohort_weights(clients))
-                        keys_s.append(jax.random.fold_in(key, 1000 + k))
-                    clients_s, take_s, smask_s = stack_plans(plans)
-                with obs_span("gather"):
-                    # the block's index uploads; the gather itself runs in
-                    # the scan body
-                    xs = (jnp.asarray(clients_s), jnp.asarray(take_s),
-                          jnp.asarray(smask_s), jnp.stack(w_s), jnp.stack(keys_s))
-            with obs_span("round", tel) as s:
-                params, opt_state, state, samp, ms = dispatch(
-                    chunk, cpool.buffers, params, opt_state, state, samp, *xs
-                )
-                s.block(ms.loss)
-            dev_metrics.append(ms)
-            done += span
-            if want_eval(done - 1):
-                dev_evals.append((done - 1, eval_fn(params, eval_batch)))
-            if t_first is None:
-                with obs_span("first_sync"):
-                    jax.block_until_ready(ms.loss)
-                t_first, first_units = time.perf_counter(), span
-            # telemetry on, s.block already synced the block, so this is an
-            # honest per-round amortisation; telemetry off it is the block's
-            # dispatch cadence (module docstring).
-            blk_ms = (time.perf_counter() - t_blk) * 1e3 / span
-            wall_ms.extend([blk_ms] * span)
-            if tel is not None or diag_on:
-                for i in range(span):
-                    kg = done - span + i
-                    row = jax.tree_util.tree_map(lambda x, i=i: x[i], ms)
-                    if diag_on and tel.want_gap(kg):
-                        tel_gap(kg, row.gap)
-                    if tel is not None:
-                        tel_round(kg, row, blk_ms)
-            if ck is not None and (done % ck.every == 0 or done == rounds):
-                # the span alignment above guarantees every every-th round
-                # ends a block; all of the block's draws are already made,
-                # so the live RNG state is the post-round-(done-1) state
-                write_ckpt(done - 1, copy.deepcopy(rng.bit_generator.state),
-                           state if use_state else None,
-                           samp if use_samp else None)
+
+    def step_state(k, clients):
+        # round k's key, and its client-state step when the run has a system
+        nonlocal state
+        kk = jax.random.fold_in(key, 1000 + k)
+        trace = None
+        if state is not None:
+            state, trace = state_step(state, kk, jnp.asarray(clients))
+        return kk, trace
+
+    def draw_host(k, n):
+        with obs_span("data", tel) as s:
+            clients = draw_cohort()
+            w = cohort_weights(clients)
+            batch = dataset.sample_round_batches(
+                rng, clients, fl.local_steps, batch_size, local_epoch
+            )
+            batch = {bk: jnp.asarray(v) for bk, v in batch.items()}
+            s.block(batch)
+        return (batch, w, *step_state(k, clients))
+
+    def draw_prefetch(k, n):
+        with obs_span("data", tel):
+            with obs_span("plan"):
+                clients = draw_cohort()
+                plan = cpool.plan(rng, clients, fl.local_steps, batch_size, local_epoch)
+            kk, trace = step_state(k, plan.clients)
+            w = cohort_weights(clients)
+            return cpool.gather(plan), w, kk, trace
+
+    def draw_scan(k, n):
+        with obs_span("data", tel):
+            with obs_span("plan"):
+                plans, w_s, keys_s = [], [], []
+                for j in range(k, k + n):
+                    clients = draw_cohort()
+                    plans.append(
+                        cpool.plan(rng, clients, fl.local_steps, batch_size, local_epoch)
+                    )
+                    w_s.append(cohort_weights(clients))
+                    keys_s.append(jax.random.fold_in(key, 1000 + j))
+                clients_s, take_s, smask_s = stack_plans(plans)
+            with obs_span("gather"):
+                # the block's index uploads; the gather itself runs in the
+                # scan body
+                return (jnp.asarray(clients_s), jnp.asarray(take_s),
+                        jnp.asarray(smask_s), jnp.stack(w_s), jnp.stack(keys_s))
+
+    compiled = False
+
+    def launch(fn, *args):
+        # one `round` span per dispatch; the call's first dispatch traces,
+        # lowers and compiles its program (or loads it from the persistent
+        # cache) inside a nested `compile` span
+        nonlocal compiled
+        with obs_span("round", tel) as s:
+            if compiled:
+                out = fn(*args)
+            else:
+                compiled = True
+                with obs_span("compile"):
+                    out = fn(*args)
+            s.block(out[-1].loss)
+        return out
+
+    def run_round(cur, k):
+        # one round's step (the chain already stepped in the draw), the
+        # diag twin on the diag_every grid
+        batch, w, kk, trace = cur
+        diag = diag_on and tel.want_gap(k)
+        if use_phased:
+            p, o, m = phased_step(params, opt_state, batch, w, kk, trace, samp,
+                                  diag=diag)
+        else:
+            p, o, m = launch(steps[diag], params, opt_state, batch, w, kk, trace,
+                             samp)
+        return p, o, state, m.sampler_state, m
+
+    def run_block(xs, k):
+        return launch(chunk, cpool.buffers, params, opt_state, state, samp, *xs)
+
+    draw = {"host": draw_host, "prefetch": draw_prefetch, "scan": draw_scan}[mode]
+    run = run_block if mode == "scan" else run_round
+    longest = rounds_per_scan if mode == "scan" else 1
+    # prefetch double-buffers: round k+1 is drawn (plan, chain step, gather
+    # dispatch) while round k's step still runs
+    ahead = mode == "prefetch"
+    nxt = draw(k0, 1) if ahead else None
+    k = k0
+    while k < rounds:
+        t_blk = time.perf_counter()
+        last = block_end(k, longest)
+        n = last - k + 1
+        if tel is not None:
+            tel.round_start(k)
+        # a checkpoint holds the RNG and chain state from just before the
+        # next block's draw: prefetch makes that draw now, before this block
+        # runs, so it snapshots them; host and scan draw after the
+        # checkpoint, so theirs is the live state
+        snap = None
+        if not ahead:
+            cur = draw(k, n)
+        elif last + 1 < rounds:
+            if need_ckpt(last):
+                snap = (copy.deepcopy(rng.bit_generator.state), state)
+            cur, nxt = nxt, draw(last + 1, 1)
+        else:
+            cur = nxt
+        params, opt_state, state, samp, ms = run(cur, k)
+        dev_metrics.append(ms)
+        if want_eval(last):
+            dev_evals.append((last, eval_fn(params, eval_batch)))
+        if mode == "host":
+            # the host loop is synchronous by construction (legacy
+            # behaviour): it blocks before assembling the next round's batch
+            jax.block_until_ready(ms.loss)
+        elif t_first is None:
+            # the only telemetry-off mid-run sync: marks the end of the
+            # compile round, and waits for all queued before it — the
+            # rest of the pool's asynchronous copy to the device too
+            with obs_span("first_sync"):
+                jax.block_until_ready(ms.loss)
+        if t_first is None:
+            t_first, first_units = time.perf_counter(), n
+        # per-round wall: honest per-round syncs in host mode (and with
+        # telemetry on, whose spans sync), dispatch cadence in prefetch,
+        # the block's cadence amortised over its rounds in scan
+        blk_ms = (time.perf_counter() - t_blk) * 1e3 / n
+        wall_ms.extend([blk_ms] * n)
+        if tel is not None:
+            for i in range(n):   # a scan block's metrics are stacked
+                row = ms if np.ndim(ms.loss) == 0 else jax.tree_util.tree_map(lambda x: x[i], ms)
+                tel_round(k + i, row, blk_ms)
+        if need_ckpt(last):
+            rng_st, cl_st = snap or (copy.deepcopy(rng.bit_generator.state), state)
+            write_ckpt(last, rng_st, cl_st, samp)
+        k = last + 1
 
     jax.block_until_ready(params)
     if dev_metrics:

@@ -50,7 +50,9 @@ from repro.sim.pool import SystemConfig, init_client_state, step_client_state
 
 _EPS = 1e-12
 
-probs_01 = st.floats(min_value=0.05, max_value=0.95, allow_nan=False, width=32)
+# bounds exact in float32: a width-32 strategy refuses a bound (0.05, 0.95)
+# that float32 cannot represent
+probs_01 = st.floats(min_value=0.0625, max_value=0.9375, allow_nan=False, width=32)
 norm_vectors = st.lists(
     st.floats(min_value=0.0, max_value=1e4, allow_nan=False, width=32),
     min_size=4,

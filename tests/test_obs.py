@@ -170,6 +170,38 @@ def test_telemetry_off_spans_in_the_trace(small_ds, tmp_path, mode, units):
                      + ["round"] * (units - 1) + ["ledger"])
 
 
+def test_host_mode_spans_in_the_trace(small_ds, tmp_path):
+    """Host mode names its host work like the pool modes, without a pool:
+    set-up, compile and ledger (holding ``ledger_read``) once, ``data`` and
+    ``round`` once per round, each round after its own ``data``; no
+    ``pool_build``, ``pool_upload``, ``plan``, ``gather`` or ``first_sync``
+    (the host loop syncs every round)."""
+    init, loss, _ = mlp_classifier(small_ds.input_dim, small_ds.num_classes,
+                                   hidden=8)
+    fl = FLConfig(n_clients=4, expected_clients=2, local_steps=2, lr_local=0.1)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        run_simulation(small_ds, init, loss, fl, 4, batch_size=4, mode="host")
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    counts = {}
+    for sp_ in spans:
+        counts[sp_[2]] = counts.get(sp_[2], 0) + 1
+    assert counts == {"setup": 1, "ledger": 1, "ledger_read": 1, "data": 4,
+                      "round": 4, "compile": 1}
+    for i, a in enumerate(spans):
+        for b in spans[i + 1:]:
+            assert b[0] >= a[1] or _inside(a, b), (a, b)
+    first_round = next(s for s in spans if s[2] == "round")
+    assert _inside(first_round, next(s for s in spans if s[2] == "compile"))
+    order = [s[2] for s in spans if s[2] != "ledger_read"]
+    assert order == (["setup", "data", "round", "compile"]
+                     + ["data", "round"] * 3 + ["ledger"])
+
+
 def test_arch_loop_spans_in_the_trace(tmp_path, monkeypatch):
     """``launch/train.py``'s arch loop names its host work as the sim
     driver does: ``setup`` once, then per round ``data``, ``round`` (the
